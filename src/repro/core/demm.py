@@ -90,13 +90,13 @@ def multiply_reduce(read_rows: jax.Array, values: jax.Array) -> jax.Array:
 def demm_spmm(packed: PackedSparse, b: jax.Array, out_dtype=jnp.float32) -> jax.Array:
     """C = A_sparse @ B with A packed as {values, indices}.
 
-    A is (R, K) packed to (R, G, Ne); B is (K, Cdim).  The product is formed
+    A is (R, K) packed to (G, Ne, R); B is (K, Cdim).  The product is formed
     group by group (each group = one pre-loaded M-row memory block of B),
     each group contributing via the two decoupled stages.  Padded slots carry
     value 0 and contribute nothing.
     """
     r, kdim = packed.shape
-    g = packed.values.shape[1]
+    g = packed.values.shape[0]
     m = packed.cfg.m
     assert b.shape[0] == kdim, (b.shape, kdim)
     cdim = b.shape[1]
@@ -104,13 +104,13 @@ def demm_spmm(packed: PackedSparse, b: jax.Array, out_dtype=jnp.float32) -> jax.
     b_blocks = b.reshape(g, m, cdim)
 
     def per_group(vals_g, idx_g, b_block):
-        # vals_g/idx_g: (R, Ne); b_block: (M, C)
-        rows = read_ports(b_block, idx_g)            # (R, Ne, C)
-        return multiply_reduce(rows, vals_g)          # (R, C)
+        # vals_g/idx_g: (Ne, R); b_block: (M, C)
+        rows = read_ports(b_block, idx_g.T)          # (R, Ne, C)
+        return multiply_reduce(rows, vals_g.T)        # (R, C)
 
     # vmap over groups, then reduce — the engine iterates groups serially in
     # hardware; the sum order is fixed (group-major) either way.
-    contribs = jax.vmap(per_group, in_axes=(1, 1, 0))(
+    contribs = jax.vmap(per_group)(
         packed.values, packed.indices, b_blocks
     )  # (G, R, C)
     return jnp.sum(contribs, axis=0).astype(out_dtype)
@@ -140,21 +140,21 @@ def demm_spmm_k_passes(packed: PackedSparse, b: jax.Array, k: int,
         raise ValueError(f"k={k} does not divide n_effective={ne}")
     split = reconfigure_k(packed, k)
     r, kdim = packed.shape
-    g = packed.values.shape[1]
+    g = packed.values.shape[0]
     m = packed.cfg.m
     cdim = b.shape[1]
     b_blocks = b.reshape(g, m, cdim)
 
-    vals = split.values.reshape(r, g, k, ne // k)
-    idx = split.indices.reshape(r, g, k, ne // k)
+    vals = split.values.reshape(g, k, ne // k, r)
+    idx = split.indices.reshape(g, k, ne // k, r)
 
     acc = jnp.zeros((r, cdim), jnp.float32)
     for pass_i in range(k):  # k is a static engine parameter (unrolled)
         def per_group(v, i, bb):
-            return multiply_reduce(read_ports(bb, i), v)
+            return multiply_reduce(read_ports(bb, i.T), v.T)
 
-        contribs = jax.vmap(per_group, in_axes=(1, 1, 0))(
-            vals[:, :, pass_i], idx[:, :, pass_i], b_blocks
+        contribs = jax.vmap(per_group)(
+            vals[:, pass_i], idx[:, pass_i], b_blocks
         )
         acc = acc + jnp.sum(contribs, axis=0)
     return acc.astype(out_dtype)

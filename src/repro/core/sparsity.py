@@ -11,15 +11,21 @@ read ports.
 Shapes
 ------
 dense   A        : (R, K)            with K % M == 0, G = K // M groups
-packed  values   : (R, G, N)         same dtype as A
-packed  indices  : (R, G, N) int32   local column index within the group,
+packed  values   : (G, N, R)         same dtype as A
+packed  indices  : (G, N, R) int32   local column index within the group,
                                      in [0, M); padded slots point at 0 with
                                      value 0 (contributing nothing).
 
+The row axis R is minor ("lane-major" layout): a TPU tiles the two minor
+dims of every array by (8, 128), so a tiny minor N axis would be padded to
+128 lanes in VMEM and force a relayout copy in front of every kernel call.
+With R minor the {value, col_idx} stream is stored, DMA'd and consumed by
+the Pallas kernels as is.
+
 The k-reconfiguration of the paper (a DeMM(N, M, C, k) engine serving kN:M
 patterns by time-sharing its N read ports over k cycles) is mirrored by
-``reconfigure_k``: a packed (R, G, kN) tensor is viewed as k passes of
-(R, G, N), preserving the engine-config semantics.
+``reconfigure_k``: a packed (G, kN, R) tensor is viewed as k passes of
+(G, N, R), preserving the engine-config semantics.
 """
 
 from __future__ import annotations
@@ -187,8 +193,8 @@ def prune(a: jax.Array, cfg: SparsityConfig) -> jax.Array:
 class PackedSparse:
     """Packed relaxed-structured-sparse matrix (the DeMM input stream)."""
 
-    values: jax.Array   # (R, G, Ne)
-    indices: jax.Array  # (R, G, Ne) int32, local in [0, M)
+    values: jax.Array   # (G, Ne, R)
+    indices: jax.Array  # (G, Ne, R) int32, local in [0, M)
     cfg: SparsityConfig
     shape: tuple        # dense (R, K)
 
@@ -224,17 +230,24 @@ def pack(a: jax.Array, cfg: SparsityConfig) -> PackedSparse:
     r, kdim = a.shape
     g = kdim // cfg.m
     ne = cfg.n_effective
-    grp = a.reshape(r, g, cfg.m)
+    # One row per (row, group): a 2-D gather compiles in about a second for
+    # the TPU where the same gather over (R, G, M) takes minutes.
+    grp = a.reshape(r * g, cfg.m)
     mag = jnp.abs(grp)
     # top_k by magnitude; indices are positions within the group.
-    _, idx = jax.lax.top_k(mag, ne)                      # (R, G, Ne)
+    _, idx = jax.lax.top_k(mag, ne)                      # (R*G, Ne)
     idx = jnp.sort(idx, axis=-1)                          # canonical order
-    vals = jnp.take_along_axis(grp, idx, axis=-1)         # (R, G, Ne)
+    vals = jnp.take_along_axis(grp, idx, axis=-1)         # (R*G, Ne)
     # Padded slots (zero values) are pointed at column 0 with value 0.
     vals = jnp.where(vals != 0, vals, jnp.zeros((), a.dtype))
     idx = jnp.where(vals != 0, idx, jnp.zeros((), jnp.int32))
-    return PackedSparse(values=vals, indices=idx.astype(jnp.int32), cfg=cfg,
-                        shape=(r, kdim))
+
+    def lane_major(x):        # (R*G, Ne) -> (G, Ne, R), once, at pack time
+        return jnp.transpose(x.reshape(r, g, ne), (1, 2, 0))
+
+    return PackedSparse(values=lane_major(vals),
+                        indices=lane_major(idx).astype(jnp.int32),
+                        cfg=cfg, shape=(r, kdim))
 
 
 @partial(jax.jit, static_argnames=("cfg", "shape"))
@@ -244,11 +257,11 @@ def unpack(values: jax.Array, indices: jax.Array, cfg: SparsityConfig,
     r, kdim = shape
     g = kdim // cfg.m
     ne = cfg.n_effective
-    assert values.shape == (r, g, ne), (values.shape, (r, g, ne))
-    # One-hot scatter: out[r, g, m] = sum_n values[r, g, n] * [indices==m]
+    assert values.shape == (g, ne, r), (values.shape, (g, ne, r))
+    # One-hot scatter: out[r, g, m] = sum_n values[g, n, r] * [indices==m]
     iota = jnp.arange(cfg.m, dtype=jnp.int32)
-    onehot = (indices[..., None] == iota).astype(values.dtype)  # (R,G,Ne,M)
-    dense = jnp.einsum("rgn,rgnm->rgm", values, onehot)
+    onehot = (indices[..., None] == iota).astype(values.dtype)  # (G,Ne,R,M)
+    dense = jnp.einsum("gnr,gnrm->rgm", values, onehot)
     return dense.reshape(r, kdim)
 
 
@@ -286,13 +299,15 @@ def expand_scales(scales: jax.Array, values: jax.Array) -> jax.Array:
 
     The single home for the rank rule every dequant site shares
     (``repro.quant``, the kernels' references, ``sparsetrain.vjp``): the
-    scale shape is a prefix of the values shape, so units owning one
-    trailing axis (per-group xwT, the block layout's per-(row-block, group,
-    row)) add one axis and per-row xwT units add two.
+    scale shape is the values shape without the Ne axis (second-minor),
+    and per-row xwT scales also drop the group axis in front of it.  So
+    units owning one Ne run (per-group xwT ``(*, G, O)``, the block layout's
+    per-(row-block, group, row) ``(*, RB, A_max, block_r)``) gain one axis
+    and per-row xwT units ``(*, O)`` gain two.
     """
     if scales.ndim == values.ndim - 1:
-        return scales[..., None]
-    return scales[..., None, None]
+        return scales[..., None, :]
+    return scales[..., None, None, :]
 
 
 class PackedWeight:
@@ -306,9 +321,9 @@ class PackedWeight:
     aux data — available at trace time for kernel dispatch and autotuning.
 
     Shapes: for the ``xwT`` layout ``values``/``indices`` are
-    ``(*stack, O, G, Ne)`` with ``G = in_features // cfg.m`` and
+    ``(*stack, G, Ne, O)`` with ``G = in_features // cfg.m`` and
     ``Ne = cfg.n_effective``.  For the ``block`` layout they are
-    ``(RB, A_max, block_r, Ne)`` with a third traced child
+    ``(RB, A_max, Ne, block_r)`` with a third traced child
     ``active_groups (RB, A_max) int32`` — the level-1 address stream that
     gates which B blocks the kernel DMAs at all — and the static block
     geometry ``block_geom = (block_r, a_max)`` rides in the aux data.
@@ -319,7 +334,7 @@ class PackedWeight:
     ``"int8"``) the ``values`` child holds quantized integers and a fourth
     traced child ``scales`` carries the symmetric dequantization scales —
     ``(*stack, O)`` float32 (per output row, the default) or
-    ``(*stack, O, G)`` (per group) for ``xwT``,
+    ``(*stack, G, O)`` (per group) for ``xwT``,
     ``(*stack, RB, A_max, block_r)`` (per row-block × group × row) for
     ``block``.  The dense weight is ``scales ⊙ values`` broadcast over the
     packed axes; kernels dequantize in-register (w8a16).
@@ -389,10 +404,10 @@ class PackedWeight:
                     raise ValueError(
                         "block layout needs block_geom=(block_r, a_max) when "
                         "values carry no shape to derive it from")
-                block_geom = (int(vshape[-2]), int(vshape[-3]))
+                block_geom = (int(vshape[-1]), int(vshape[-3]))
             block_geom = (int(block_geom[0]), int(block_geom[1]))
             if vshape is not None and len(vshape) >= 4:
-                rb, amax, br, ne = (int(d) for d in vshape[-4:])
+                rb, amax, ne, br = (int(d) for d in vshape[-4:])
                 if (ne != cfg.n_effective or br != block_geom[0]
                         or amax != block_geom[1] or rb * br != dense_shape[0]):
                     raise ValueError(
@@ -400,8 +415,8 @@ class PackedWeight:
                         f"block_geom={block_geom} over dense {dense_shape} "
                         f"at cfg={cfg.pattern_name()}: expected "
                         f"(*, {dense_shape[0] // block_geom[0]}, "
-                        f"{block_geom[1]}, {block_geom[0]}, "
-                        f"{cfg.n_effective})")
+                        f"{block_geom[1]}, {cfg.n_effective}, "
+                        f"{block_geom[0]})")
             if (shard_axis is not None and vshape is not None
                     and len(vshape) >= 5 and int(vshape[-5]) != shards):
                 raise ValueError(
@@ -414,16 +429,17 @@ class PackedWeight:
                     f"active_groups/block_geom only apply to the "
                     f"{LAYOUT_BLOCK!r} layout, not {layout!r}")
             if vshape is not None and len(vshape) >= 3:
-                g, ne = int(vshape[-2]), int(vshape[-1])
+                g, ne, o = (int(d) for d in vshape[-3:])
                 # Shard-stacked values hold G // shards groups per slice.
                 span = shards if shard_axis is not None else 1
-                if ne != cfg.n_effective or g * cfg.m * span != dense_shape[1]:
+                if (ne != cfg.n_effective or o != dense_shape[0]
+                        or g * cfg.m * span != dense_shape[1]):
                     raise ValueError(
                         f"values shape {tuple(vshape)} is inconsistent with "
                         f"the packed layout of cfg={cfg.pattern_name()} over "
                         f"dense {dense_shape}: expected "
                         f"(*, {dense_shape[1] // (cfg.m * span)}, "
-                        f"{cfg.n_effective})")
+                        f"{cfg.n_effective}, {dense_shape[0]})")
             if (shard_axis is not None and vshape is not None
                     and len(vshape) >= 4 and int(vshape[-4]) != shards):
                 raise ValueError(
@@ -432,12 +448,13 @@ class PackedWeight:
                     f"{shards}")
         sshape = getattr(scales, "shape", None)
         if qdtype is not None and sshape is not None and vshape is not None:
+            per_unit = tuple(vshape[:-2]) + tuple(vshape[-1:])
             if layout == LAYOUT_BLOCK:
-                want = (tuple(vshape[:-1]),)
+                want = (per_unit,)
             else:
                 # xwT grants two granularities (repro.quant): per output
-                # row (*stack, O) or per (row, group) (*stack, O, G).
-                want = (tuple(vshape[:-2]), tuple(vshape[:-1]))
+                # row (*stack, O) or per (group, row) (*stack, G, O).
+                want = (tuple(vshape[:-3]) + tuple(vshape[-1:]), per_unit)
             if tuple(sshape) not in want:
                 raise ValueError(
                     f"scales shape {tuple(sshape)} does not match values "
@@ -481,7 +498,7 @@ class PackedWeight:
     @property
     def stack_dims(self) -> tuple:
         """Leading (scan/vmap) stack dims in front of the layout's core:
-        (O, G, Ne) for ``xwT``, (RB, A_max, block_r, Ne) for ``block``.
+        (G, Ne, O) for ``xwT``, (RB, A_max, Ne, block_r) for ``block``.
         The shard-stacked form's shard dim sits between the stack dims and
         the core (so layer-scan still slices axis 0) and is not a stack
         dim."""
@@ -565,10 +582,12 @@ class PackedWeight:
         vals, idxs = self.dequantized_values(), self.indices
         stack = self.stack_dims
         if stack:
-            vals = vals.reshape(-1, *vals.shape[-2:])
-            idxs = idxs.reshape(-1, *idxs.shape[-2:])
-        dense = unpack(vals, idxs, self.cfg, (vals.shape[0], k))
-        return dense.reshape(*stack, o, k) if stack else dense
+            vals = vals.reshape(-1, *vals.shape[-3:])
+            idxs = idxs.reshape(-1, *idxs.shape[-3:])
+            dense = jax.vmap(lambda v, i: unpack(v, i, self.cfg, (o, k)))(
+                vals, idxs)
+            return dense.reshape(*stack, o, k)
+        return unpack(vals, idxs, self.cfg, (o, k))
 
 
 def _pw_flatten(pw: PackedWeight):
@@ -678,7 +697,7 @@ def pack_block(a: jax.Array, cfg: SparsityConfig, *,
     contribute nothing.
 
     Returns a :class:`PackedWeight` with ``layout="block"``, traced children
-    ``values``/``indices`` ``(RB, A_max, block_r, Ne)`` +
+    ``values``/``indices`` ``(RB, A_max, Ne, block_r)`` +
     ``active_groups (RB, A_max) int32``, and static
     ``block_geom=(block_r, a_max)`` in the aux.
     """
@@ -727,6 +746,8 @@ def pack_block(a: jax.Array, cfg: SparsityConfig, *,
     # Padded slots alias group 0: zero them so duplicates contribute nothing.
     vals = jnp.where(active[:, :, None, None], vals, jnp.zeros((), a.dtype))
     idx = jnp.where(vals != 0, idx, jnp.zeros((), jnp.int32))
+    # Lane-major storage: (RB, A, br, Ne) -> (RB, A, Ne, br).
+    vals, idx = jnp.swapaxes(vals, -1, -2), jnp.swapaxes(idx, -1, -2)
     return PackedWeight(vals, idx.astype(jnp.int32), cfg=cfg,
                         dense_shape=(r, kdim), layout=LAYOUT_BLOCK,
                         active_groups=ag, block_geom=(block_r, a_max))
@@ -738,8 +759,8 @@ def pack_block_stacked(w: jax.Array, cfg: SparsityConfig, *,
     """:func:`pack_block` for layer-stacked weights ``(*lead, O, K)``.
 
     All slices share one static ``a_max`` (the max active-group count over
-    the stack) so the packed children stack to ``(*lead, RB, A_max, block_r,
-    Ne)`` / ``(*lead, RB, A_max)`` and ``jax.lax.scan`` can slice the layer
+    the stack) so the packed children stack to ``(*lead, RB, A_max, Ne,
+    block_r)`` / ``(*lead, RB, A_max)`` and ``jax.lax.scan`` can slice the layer
     axis off exactly as for the xwT layout; ``dense_shape``/``block_geom``
     stay the per-layer statics."""
     lead = tuple(w.shape[:-2])
@@ -783,12 +804,12 @@ def unpack_block(active_groups: jax.Array, values: jax.Array,
     Duplicate active-group ids accumulate (matching the kernel's
     revisit-accumulate semantics); padded all-zero slots contribute 0."""
     r, kdim = shape
-    rb, a_max, block_r, ne = values.shape
+    rb, a_max, ne, block_r = values.shape
     g = kdim // cfg.m
     assert rb * block_r == r, (values.shape, shape)
     iota = jnp.arange(cfg.m, dtype=jnp.int32)
     onehot = (indices[..., None] == iota).astype(values.dtype)
-    per_slot = jnp.einsum("rabn,rabnm->rabm", values, onehot)  # (RB,A,br,M)
+    per_slot = jnp.einsum("ranb,ranbm->rabm", values, onehot)  # (RB,A,br,M)
 
     def per_block(ag_b, slot_b):
         dense_b = jnp.zeros((block_r, g, cfg.m), values.dtype)
@@ -864,15 +885,14 @@ def shard_packed_row_parallel(pw: "PackedWeight", num_shards: int, *,
 
     if pw.layout == LAYOUT_XWT:
         vals, idx = pw.values, pw.indices
-        # (*stack, O, G, Ne) -> (*stack, O, S, Gl, Ne) -> swap O and S
+        # (*stack, G, Ne, O) -> (*stack, S, Gl, Ne, O): a pure reshape
         def reshard3(x):
-            x = x.reshape(*x.shape[:-2], num_shards, gl, x.shape[-1])
-            return jnp.swapaxes(x, -4, -3)
+            return x.reshape(*x.shape[:-3], num_shards, gl, *x.shape[-2:])
         scales = pw.scales
         if scales is not None:
-            if scales.ndim == vals.ndim - 1:      # per-group (*stack, O, G)
-                scales = scales.reshape(*scales.shape[:-1], num_shards, gl)
-                scales = jnp.swapaxes(scales, -3, -2)
+            if scales.ndim == vals.ndim - 1:      # per-group (*stack, G, O)
+                scales = scales.reshape(*scales.shape[:-2], num_shards, gl,
+                                        scales.shape[-1])
             else:                                  # per-row (*stack, O)
                 scales = jnp.broadcast_to(
                     scales[..., None, :],
@@ -924,16 +944,15 @@ def unshard_packed(pw: "PackedWeight") -> "PackedWeight":
     nstack = len(pw.stack_dims)
 
     if pw.layout == LAYOUT_XWT:
-        def merge3(x):  # (*stack, S, O, Gl, Ne) -> (*stack, O, G, Ne)
-            x = jnp.swapaxes(x, -4, -3)
-            return x.reshape(*x.shape[:-3], x.shape[-3] * x.shape[-2],
-                             x.shape[-1])
+        def merge3(x):  # (*stack, S, Gl, Ne, O) -> (*stack, G, Ne, O)
+            return x.reshape(*x.shape[:-4], x.shape[-4] * x.shape[-3],
+                             *x.shape[-2:])
         scales = pw.scales
         if scales is not None:
             if scales.ndim == pw.values.ndim - 1:  # per-group
-                scales = jnp.swapaxes(scales, -3, -2)
-                scales = scales.reshape(*scales.shape[:-2],
-                                        scales.shape[-2] * scales.shape[-1])
+                scales = scales.reshape(*scales.shape[:-3],
+                                        scales.shape[-3] * scales.shape[-2],
+                                        scales.shape[-1])
             else:                                   # per-row: replicated
                 scales = jax.lax.index_in_dim(scales, 0, axis=scales.ndim - 2,
                                               keepdims=False)
@@ -1029,10 +1048,10 @@ def tier_sort_packed(pw: PackedWeight) -> PackedWeight:
     """
     mag = jnp.abs(pw.values.astype(jnp.float32)
                   if pw.qdtype is not None else pw.values)
-    order = jnp.argsort(-mag, axis=-1, stable=True)
+    order = jnp.argsort(-mag, axis=-2, stable=True)      # the Ne axis
     return pw.replace(
-        values=jnp.take_along_axis(pw.values, order, axis=-1),
-        indices=jnp.take_along_axis(pw.indices, order, axis=-1))
+        values=jnp.take_along_axis(pw.values, order, axis=-2),
+        indices=jnp.take_along_axis(pw.indices, order, axis=-2))
 
 
 def narrow_tier(pw: PackedWeight) -> PackedWeight:
@@ -1045,7 +1064,7 @@ def narrow_tier(pw: PackedWeight) -> PackedWeight:
     if t is None:
         return pw
     return pw.replace(
-        values=pw.values[..., :t], indices=pw.indices[..., :t],
+        values=pw.values[..., :t, :], indices=pw.indices[..., :t, :],
         cfg=SparsityConfig(n=t, m=pw.cfg.m, k=1), tier_ne=None)
 
 
@@ -1054,20 +1073,18 @@ def reconfigure_k(p: PackedSparse, k: int) -> PackedSparse:
 
     Mirrors the paper's §II-B reconfiguration: an engine with N read ports
     serves a kN:M pattern by reading the same B block k times.  The packed
-    (R, G, kN) tensors are reshaped to (R, G*k', ...) views consumed pass by
+    (G, kN, R) tensors are reshaped to (G*k, N, R) views consumed pass by
     pass; numerically ``sum_k demm(pass_k) == demm(full)``.
     """
     ne = p.cfg.n_effective
     if ne % k:
         raise ValueError(f"cannot split n_effective={ne} into k={k} passes")
     n_pass = ne // k
-    r, g, _ = p.values.shape
-    vals = p.values.reshape(r, g, k, n_pass)
-    idx = p.indices.reshape(r, g, k, n_pass)
+    g, _, r = p.values.shape
     return dataclasses.replace(
         p,
-        values=vals.reshape(r, g * k, n_pass),
-        indices=idx.reshape(r, g * k, n_pass),
+        values=p.values.reshape(g * k, n_pass, r),
+        indices=p.indices.reshape(g * k, n_pass, r),
         cfg=SparsityConfig(n=n_pass, m=p.cfg.m, k=k),
     )
 

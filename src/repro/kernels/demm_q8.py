@@ -1,28 +1,27 @@
 """Pallas TPU kernels: int8 quantized DeMM matmuls (w8a16).
 
 Quantized twins of ``demm_spmm.demm_xwT_pallas`` and
-``demm_block_spmm.demm_block_spmm_pallas`` for weights produced by
+``demm_block_spmm.demm_block_xwT_pallas`` for weights produced by
 ``repro.quant.quantize_packed``: the packed ``values`` stream is int8 (a
 further 2–4× cut of the already-compressed weight HBM traffic on top of the
 sparsity win) and dequantization happens **in-register**, after the DMA
 stage — only quantized bytes ever leave HBM.
 
 w8a16 scheme: weights int8, activations keep their serving dtype
-(bf16/f32).  Inside the kernel the int8 values are cast to the activation
-dtype while building the (rows, M) scatter matrix S — int8 magnitudes
-(≤127, ≤254 after duplicate-index accumulation) are exact in bf16 — and the
-symmetric scales fold in as one row-wise multiply on S before the MXU
-matmul, so the fused body costs one extra VPU multiply per tile:
+(bf16/f32).  Inside the kernel the int8 values are cast to fp32 while
+building the scatter matrix Sᵀ, and the symmetric scales fold
+into each packed ``(1, block_o)`` row before the select, so the fused body
+costs one extra VPU multiply per packed row:
 
-  * xwT:   scales are per output row ``(O,)`` → S rows scale by
-    ``scales[o]`` (passed as an ``(O, 1)`` operand so the BlockSpec stays
-    2-D).  Per-group scales ``(O, G)`` (``repro.quant`` granularity
-    ``"per_group"``) cost the same: grid step ``g`` reads column ``g`` of
-    the scales operand instead of column 0.
+  * xwT:   scales are per output row ``(O,)`` → every packed row of the
+    tile scales by the same ``(1, block_o)`` lane row (passed as a
+    ``(1, O)`` operand).  Per-group scales ``(G, O)`` (``repro.quant``
+    granularity ``"per_group"``) cost the same: group ``g`` of the chunk
+    reads row ``g`` of the scales strip instead.
   * block: scales are per (row-block, group, row) ``(RB, A_max, block_r)``
-    → the ``(block_r, M)`` scatter tile scales row-wise per grid step, and
-    the level-1 active-group prefetch (the decoupled address stream) is
-    untouched.
+    → grid step ``j`` scales its slot by row ``j`` of the row block's
+    scales, and the level-1 active-group prefetch (the decoupled address
+    stream) is untouched.
 
 Accumulation stays fp32, matching the float kernels' oracle tolerance.
 """
@@ -37,14 +36,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparsity import SparsityConfig
+from repro.kernels.demm_block_spmm import (DEFAULT_BLOCK_C, block_specs,
+                                           group_major)
 from repro.kernels.demm_spmm import (
-    _CompilerParams,
-    _pad_to,
-    _scatter_matrix,
+    SUBLANES,
     DEFAULT_BLOCK_B,
     DEFAULT_BLOCK_R,
+    _scatter_matrix,
+    accumulate,
+    fit_tile,
+    xwT_grid,
 )
-from repro.kernels.demm_block_spmm import DEFAULT_BLOCK_C
 
 
 # ---------------------------------------------------------------------------
@@ -52,24 +54,22 @@ from repro.kernels.demm_block_spmm import DEFAULT_BLOCK_C
 # ---------------------------------------------------------------------------
 
 def _xwT_q8_kernel(x_ref, values_ref, indices_ref, scales_ref, out_ref, *,
-                   m, n):
-    g = pl.program_id(2)
+                   m, per_group, interpret):
+    c = pl.program_id(2)
 
-    @pl.when(g == 0)
+    @pl.when(c == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # int8 → activation dtype inside the scatter expansion (in-register
-    # dequant), then one row-wise multiply by the per-output-row scale.
-    s = _scatter_matrix(values_ref[...], indices_ref[...], m, n,
-                        x_ref.dtype)                            # (Ot, M)
-    s = s * scales_ref[...].astype(x_ref.dtype)                 # (Ot, 1)
-    contrib = jax.lax.dot_general(
-        x_ref[...], s,
-        dimension_numbers=(((1,), (1,)), ((), ())),             # contract M
-        preferred_element_type=jnp.float32,
-    )                                                           # (Bt, Ot)
-    out_ref[...] += contrib
+    # int8 → fp32 inside the scatter expansion (in-register dequant), each
+    # packed row scaled by its group's (or its output row's) scale.
+    chunk = values_ref.shape[0]
+    if per_group:
+        rows = [scales_ref[pl.ds(c * chunk + g, 1), :] for g in range(chunk)]
+    else:
+        rows = [scales_ref[...]] * chunk
+    s = _scatter_matrix(values_ref, indices_ref, m, scales=rows)
+    accumulate(out_ref, x_ref[...], s, interpret)
 
 
 @functools.partial(
@@ -78,9 +78,9 @@ def _xwT_q8_kernel(x_ref, values_ref, indices_ref, scales_ref, out_ref, *,
 )
 def demm_xwT_q8_pallas(
     x: jax.Array,           # (Bx, K) dense activations
-    values: jax.Array,      # (O, G, N) int8 packed weight
-    indices: jax.Array,     # (O, G, N) int32
-    scales: jax.Array,      # (O,) per-row or (O, G) per-group f32 scales
+    values: jax.Array,      # (G, Ne, O) int8 packed weight
+    indices: jax.Array,     # (G, Ne, O) int32
+    scales: jax.Array,      # (O,) per-row or (G, O) per-group f32 scales
     cfg: SparsityConfig,
     *,
     block_b: int = DEFAULT_BLOCK_B,
@@ -88,123 +88,96 @@ def demm_xwT_q8_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     bx, k = x.shape
-    o, g, n = values.shape
+    g, n, o = values.shape
     m = cfg.m
     assert k == g * m, (k, g, m)
     assert n == cfg.n_effective, (n, cfg)
-    assert scales.shape in ((o,), (o, g)), (scales.shape, values.shape)
+    assert scales.shape in ((o,), (g, o)), (scales.shape, values.shape)
     per_group = scales.ndim == 2
-    block_b = min(block_b, bx)
-    block_o = min(block_o, o)
-    x = _pad_to(x, 0, block_b)
-    values = _pad_to(values, 0, block_o)
-    indices = _pad_to(indices, 0, block_o)
-    # Per-row scales ride as an (O, 1) operand so the BlockSpec stays 2-D;
-    # per-group scales ride as (O, G) and grid step gg picks its column —
-    # the kernel body sees a (block_o, 1) tile either way.
-    scales2d = _pad_to(scales if per_group else scales.reshape(o, 1), 0,
-                       block_o)
-    bxp, op = x.shape[0], values.shape[0]
-
-    grid = (bxp // block_b, op // block_o, g)
-    kernel = functools.partial(_xwT_q8_kernel, m=m, n=n)
-    scales_map = ((lambda i, j, gg: (j, gg)) if per_group
-                  else (lambda i, j, gg: (j, 0)))
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
+    block_b, block_o, chunk = xwT_grid(x.shape, values.shape, m, block_b,
+                                       block_o)
+    # Per-row scales ride as one (1, O) row; per-group scales as the whole
+    # (G, O) column strip of the output tile, indexed by the chunk's groups.
+    if per_group:
+        scales_spec = pl.BlockSpec((g, block_o), lambda i, j, c: (0, j))
+    else:
+        scales = scales.reshape(1, o)
+        scales_spec = pl.BlockSpec((1, block_o), lambda i, j, c: (0, j))
+    return pl.pallas_call(
+        functools.partial(_xwT_q8_kernel, m=m, per_group=per_group,
+                          interpret=interpret),
+        grid=(bx // block_b, o // block_o, g // chunk),
         in_specs=[
-            pl.BlockSpec((block_b, m), lambda i, j, gg: (i, gg)),
-            pl.BlockSpec((block_o, 1, n), lambda i, j, gg: (j, gg, 0)),
-            pl.BlockSpec((block_o, 1, n), lambda i, j, gg: (j, gg, 0)),
-            pl.BlockSpec((block_o, 1), scales_map),
+            pl.BlockSpec((block_b, chunk * m), lambda i, j, c: (i, c)),
+            pl.BlockSpec((chunk, n, block_o), lambda i, j, c: (c, 0, j)),
+            pl.BlockSpec((chunk, n, block_o), lambda i, j, c: (c, 0, j)),
+            scales_spec,
         ],
-        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, gg: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((bxp, op), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, c: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((bx, o), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="demm_xwT_q8",
-    )(x, values, indices, scales2d)
-    return out[:bx, :o]
+    )(x, values, indices, scales)
 
 
 # ---------------------------------------------------------------------------
-# C = A_q8_block @ B (two-level layout, scalar-prefetch address stream)
+# y = x @ W_q8_blockᵀ (two-level layout, scalar-prefetch address stream)
 # ---------------------------------------------------------------------------
 
-def _block_q8_kernel(ag_ref, values_ref, indices_ref, scales_ref, b_ref,
-                     out_ref, *, m, n):
+def _block_q8_kernel(ag_ref, x_ref, values_ref, indices_ref, scales_ref,
+                     out_ref, *, m, interpret):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    vals = values_ref[0]                                     # (1, block_r, N)
-    idxs = indices_ref[0]
-    s = _scatter_matrix(
-        jnp.swapaxes(vals, 0, 1), jnp.swapaxes(idxs, 0, 1), m, n, b_ref.dtype
-    )                                                        # (block_r, M)
-    s = s * scales_ref[0, 0][:, None].astype(b_ref.dtype)
-    out_ref[...] += jax.lax.dot_general(
-        s, b_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    s = _scatter_matrix(values_ref, indices_ref, m,
+                        scales=[scales_ref[pl.ds(j, 1), :]])  # (M, block_r)
+    accumulate(out_ref, x_ref[...], s, interpret)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "r", "cd_block", "interpret"),
+    static_argnames=("cfg", "cd_block", "interpret"),
 )
-def demm_block_spmm_q8_pallas(
+def demm_block_xwT_q8_pallas(
+    x: jax.Array,              # (Bx, K) dense activations
+    values: jax.Array,         # (RB, A_max, Ne, block_r) int8
+    indices: jax.Array,        # (RB, A_max, Ne, block_r)
     active_groups: jax.Array,  # (RB, A_max) int32
-    values: jax.Array,         # (RB, A_max, block_r, Ne) int8
-    indices: jax.Array,        # (RB, A_max, block_r, Ne)
     scales: jax.Array,         # (RB, A_max, block_r) float32
-    b: jax.Array,              # (K, Cd)
     cfg: SparsityConfig,
     *,
-    r: int,
     cd_block: int = DEFAULT_BLOCK_C,
     interpret: bool = False,
 ) -> jax.Array:
-    rb, a_max, block_r, n = values.shape
-    k, cd = b.shape
+    bx, k = x.shape
+    rb, a_max, n, block_r = values.shape
     m = cfg.m
-    assert rb * block_r == r
-    assert n == cfg.n_effective
+    assert k % m == 0 and n == cfg.n_effective, (x.shape, values.shape, cfg)
     assert scales.shape == (rb, a_max, block_r), (scales.shape, values.shape)
-    cd_block = min(cd_block, cd)
-    assert cd % cd_block == 0
-
-    grid = (rb, cd // cd_block, a_max)
-    kernel = functools.partial(_block_q8_kernel, m=m, n=n)
+    block_b = fit_tile(bx, cd_block, SUBLANES)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_block_q8_kernel, m=m, interpret=interpret),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, block_r, n),
-                             lambda i, c, j, ag: (i, j, 0, 0)),
-                pl.BlockSpec((1, 1, block_r, n),
-                             lambda i, c, j, ag: (i, j, 0, 0)),
-                pl.BlockSpec((1, 1, block_r),
-                             lambda i, c, j, ag: (i, j, 0)),
-                # Decoupled read port (unchanged by quantization): B's DMA
-                # address comes from the prefetched active-group id.
-                pl.BlockSpec((m, cd_block), lambda i, c, j, ag: (ag[i, j], c)),
+            grid=(bx // block_b, rb, a_max),
+            in_specs=block_specs(values.shape, block_b, m) + [
+                # All of a row block's slot scales; step j reads row j.
+                pl.BlockSpec((None, a_max, block_r),
+                             lambda b, i, j, ag: (i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((block_r, cd_block),
-                                   lambda i, c, j, ag: (i, c)),
+            out_specs=pl.BlockSpec((block_b, block_r),
+                                   lambda b, i, j, ag: (b, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((r, cd), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bx, rb * block_r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="demm_block_spmm_q8",
-    )(active_groups, values, indices, scales, b)
+    )(active_groups, group_major(x, m), values, indices, scales)

@@ -1,28 +1,33 @@
 """Pallas TPU kernel: fused decompress→MXU DeMM spmm.
 
 TPU adaptation of the DeMM engine (DESIGN.md §2).  The packed sparse matrix
-(values + column indices) is the only representation of A that leaves HBM.
+(values + column indices) is the only representation of W that leaves HBM.
 Inside the kernel — i.e. *after* the DMA stage, in VMEM — the N
-``{value, col_idx}`` pairs of each row-group are expanded into a (rows, M)
-scatter matrix S (the software analogue of DeMM's N read ports selecting N
-rows of the pre-loaded B block), and the MXU performs S @ B_block, fusing the
-paper's multiplier array and adder trees into the systolic matmul.
+``{value, col_idx}`` pairs of each M-group are expanded into the transposed
+scatter matrix Sᵀ (M rows per group, one lane per output row: the software
+analogue of DeMM's N read ports selecting N rows of the pre-loaded x block),
+and the MXU performs x_chunk @ Sᵀ, fusing the paper's multiplier array and
+adder trees into the systolic matmul.
 
 Two entry points:
 
-* ``demm_spmm_pallas(values, indices, b)``   — C = A_sparse @ B
-  (the paper's orientation: A (R, K) packed, B (K, Cd) dense).
 * ``demm_xwT_pallas(x, values, indices)``    — y = x @ W_sparseᵀ
   (the serving hot path: dense activations × packed weightᵀ).
+* ``demm_spmm_pallas(values, indices, b)``   — C = A_sparse @ B
+  (the paper's orientation), evaluated as ``(Bᵀ @ Aᵀ)ᵀ`` by the same kernel.
 
-Both tile with explicit BlockSpecs: the B (resp. x) block of one M-group is
-resident in VMEM across the inner grid dimension, mirroring the engine's
-pre-loaded memory block; the output block is revisited across groups and
-accumulated in fp32.
+Layout (``core.sparsity``): values/indices are lane-major ``(G, Ne, O)`` —
+the output axis O is minor, so a ``(chunk, Ne, block_o)`` block is a legal
+TPU tile for every pattern (Ne equals the full array dim, block_o is a
+multiple of 128 or all of O) and the stream is DMA'd exactly as stored.
+Each grid step consumes ``chunk`` consecutive groups, ``chunk * M`` columns
+of x: the smallest chunk whose x block spans a multiple of 128 lanes, so
+fine patterns (2:4, 8:16) feed the MXU a full-lane contraction.  The output
+block is revisited across chunks and accumulated in fp32.
 
-VMEM budget (defaults, bf16): B block M×Ct = 128×256×2 = 64 KiB; A packed
-block Rt×N×(2+4) ≈ 6 KiB; out block Rt×Ct×4 = 128 KiB — comfortably inside
-the ~16 MiB/core VMEM with double buffering.
+VMEM budget (defaults, bf16 x, 8:128, block_o = 128): x block Bt×128×2,
+values + indices 8×128×(2+4) = 6 KiB, Sᵀ 128×128×4 = 64 KiB, out block
+Bt×128×4 — far inside the scoped VMEM limit with double buffering.
 """
 
 from __future__ import annotations
@@ -36,141 +41,97 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparsity import SparsityConfig
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
-# MXU/VPU-aligned defaults.  Production dispatch picks per-problem tiles via
-# repro.tune (backend="auto"); these remain the direct-call defaults.
-DEFAULT_BLOCK_R = 128   # rows of the sparse matrix per tile
-DEFAULT_BLOCK_C = 256   # dense output columns per tile
+# Direct-call defaults.  Production dispatch picks per-problem tiles via
+# repro.tune (backend="auto").
+DEFAULT_BLOCK_R = 128   # output rows (lanes) per tile
+DEFAULT_BLOCK_C = 256   # dense output columns per tile (paper orientation)
 DEFAULT_BLOCK_B = 128   # activation rows per tile (xwT orientation)
 
+LANES = 128
+SUBLANES = 8
 
-def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
-    """Zero-pad ``axis`` up to the next multiple (no-op when aligned).
 
-    Zero rows of packed values scatter to zero contributions and padded
-    output rows/columns are sliced away by the caller, so ragged serving
-    shapes (batch not a tile multiple) stay exact.
+def fit_tile(dim: int, want: int, align: int) -> int:
+    """Largest tile <= ``want`` that divides ``dim`` and is a multiple of
+    ``align``; the whole ``dim`` when none exists (a block equal to the
+    array dim is always a legal TPU block)."""
+    if dim <= want:
+        return dim
+    for t in range(want - want % align, 0, -align):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def group_chunk(groups: int, m: int) -> int:
+    """Groups per grid step: the smallest divisor of ``groups`` whose
+    ``chunk * m`` columns fill whole 128-lane tiles (all groups if none)."""
+    for d in range(1, groups + 1):
+        if groups % d == 0 and (d * m) % LANES == 0:
+            return d
+    return groups
+
+
+def _scatter_matrix(values_ref, indices_ref, m: int, scales=None):
+    """Expand a packed ``(chunk, N, cols)`` block into the fp32 scatter
+    matrix Sᵀ ``(chunk * M, cols)`` — the in-VMEM image of DeMM's N read
+    ports:
+
+        Sᵀ[g*M + j, c] = sum_n values[g, n, c] * [indices[g, n, c] == j]
+
+    The chunk and N loops are static and small, so they unroll into
+    select-accumulate passes over the tile.  Each packed row is a ``(1,
+    cols)`` slice broadcast along sublanes, and the select runs in fp32 (the
+    VPU's native width), so the same body lowers for f32, bf16 and int8
+    values.  ``scales`` (optional) holds one ``(1, cols)`` fp32 row per
+    group of the chunk, folded into the values before the select (the
+    in-register w8a16 dequant).  Duplicate indices accumulate, matching the
+    oracle's scatter-add.
     """
-    size = x.shape[axis]
-    pad = (-size) % multiple
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
-
-
-def _scatter_matrix(values_blk, indices_blk, m: int, n: int, dtype):
-    """Expand packed (rows, 1, N) values/indices into the (rows, M) scatter
-    matrix S — the in-VMEM image of DeMM's N read ports.
-
-    S[r, j] = sum_n values[r, n] * [indices[r, n] == j]
-
-    The N loop is static and small (the paper's read-port count), so it is
-    unrolled into N VPU select-accumulate ops over (rows, M) tiles.
-    Duplicate indices accumulate, matching the oracle's scatter-add.
-    """
-    rows = values_blk.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (rows, m), 1)
-    s = jnp.zeros((rows, m), dtype)
-    for j in range(n):
-        v = values_blk[:, 0, j].astype(dtype)[:, None]        # (rows, 1)
-        idx = indices_blk[:, 0, j][:, None]                    # (rows, 1)
-        s = s + jnp.where(idx == iota, v, jnp.zeros((), dtype))
+    chunk, n, cols = values_ref.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk * m, cols), 0)
+    s = jnp.zeros((chunk * m, cols), jnp.float32)
+    for g in range(chunk):
+        for j in range(n):
+            v = values_ref[g, j:j + 1, :].astype(jnp.float32)
+            if scales is not None:
+                v = v * scales[g]
+            target = indices_ref[g, j:j + 1, :] + g * m
+            s = s + jnp.where(rows == target, v, 0.0)
     return s
 
 
+def accumulate(out_ref, x, s, interpret: bool):
+    """``out += x @ s`` on the MXU: s rounded to x's dtype, fp32
+    accumulation.  Interpret mode runs on the CPU, whose dot has no
+    bf16 × bf16 → f32 form, so there both operands are widened first —
+    the same products, which are exact in fp32."""
+    s = s.astype(x.dtype)
+    if interpret:
+        x, s = x.astype(jnp.float32), s.astype(jnp.float32)
+    out_ref[...] += jnp.dot(x, s, preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
-# C = A_sparse @ B (paper orientation)
+# y = x @ W_sparseᵀ (serving orientation: W packed (G, Ne, O), x (Bx, K))
 # ---------------------------------------------------------------------------
 
-def _spmm_kernel(values_ref, indices_ref, b_ref, out_ref, *, m, n, n_groups):
-    g = pl.program_id(2)
-
-    @pl.when(g == 0)
+def _xwT_kernel(x_ref, values_ref, indices_ref, out_ref, *, m, interpret):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    s = _scatter_matrix(values_ref[...], indices_ref[...], m, n,
-                        b_ref.dtype)                            # (Rt, M)
-    contrib = jax.lax.dot_general(
-        s, b_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                           # (Rt, Ct)
-    out_ref[...] += contrib
+    s = _scatter_matrix(values_ref, indices_ref, m)             # (Kc, Ot)
+    accumulate(out_ref, x_ref[...], s, interpret)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "block_r", "block_c", "interpret"),
-)
-def demm_spmm_pallas(
-    values: jax.Array,      # (R, G, N)
-    indices: jax.Array,     # (R, G, N) int32
-    b: jax.Array,           # (K, Cd), K = G * M
-    cfg: SparsityConfig,
-    *,
-    block_r: int = DEFAULT_BLOCK_R,
-    block_c: int = DEFAULT_BLOCK_C,
-    interpret: bool = False,
-) -> jax.Array:
-    r, g, n = values.shape
-    k, cd = b.shape
-    m = cfg.m
-    assert k == g * m, (k, g, m)
-    assert n == cfg.n_effective, (n, cfg)
-    block_r = min(block_r, r)
-    block_c = min(block_c, cd)
-    # Ragged shapes are zero-padded to the tile grid and sliced back after.
-    values = _pad_to(values, 0, block_r)
-    indices = _pad_to(indices, 0, block_r)
-    b = _pad_to(b, 1, block_c)
-    rp, cdp = values.shape[0], b.shape[1]
-
-    grid = (rp // block_r, cdp // block_c, g)
-    kernel = functools.partial(_spmm_kernel, m=m, n=n, n_groups=g)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r, 1, n), lambda i, j, gg: (i, gg, 0)),
-            pl.BlockSpec((block_r, 1, n), lambda i, j, gg: (i, gg, 0)),
-            pl.BlockSpec((m, block_c), lambda i, j, gg: (gg, j)),
-        ],
-        out_specs=pl.BlockSpec((block_r, block_c), lambda i, j, gg: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rp, cdp), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="demm_spmm",
-    )(values, indices, b)
-    return out[:r, :cd]
-
-
-# ---------------------------------------------------------------------------
-# y = x @ W_sparseᵀ (serving orientation: W packed (O, K), x (Bx, K))
-# ---------------------------------------------------------------------------
-
-def _xwT_kernel(x_ref, values_ref, indices_ref, out_ref, *, m, n):
-    g = pl.program_id(2)
-
-    @pl.when(g == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    s = _scatter_matrix(values_ref[...], indices_ref[...], m, n,
-                        x_ref.dtype)                            # (Ot, M)
-    contrib = jax.lax.dot_general(
-        x_ref[...], s,
-        dimension_numbers=(((1,), (1,)), ((), ())),             # contract M
-        preferred_element_type=jnp.float32,
-    )                                                           # (Bt, Ot)
-    out_ref[...] += contrib
+def xwT_grid(x_shape, values_shape, m: int, block_b: int, block_o: int):
+    """(block_b, block_o, chunk) actually used for a problem — legal TPU
+    tiles derived from the requested ones (see :func:`fit_tile`)."""
+    bx = x_shape[0]
+    g, _n, o = values_shape
+    return (fit_tile(bx, block_b, SUBLANES), fit_tile(o, block_o, LANES),
+            group_chunk(g, m))
 
 
 @functools.partial(
@@ -179,8 +140,8 @@ def _xwT_kernel(x_ref, values_ref, indices_ref, out_ref, *, m, n):
 )
 def demm_xwT_pallas(
     x: jax.Array,           # (Bx, K) dense activations
-    values: jax.Array,      # (O, G, N) packed weight
-    indices: jax.Array,     # (O, G, N) int32
+    values: jax.Array,      # (G, Ne, O) packed weight
+    indices: jax.Array,     # (G, Ne, O) int32
     cfg: SparsityConfig,
     *,
     block_b: int = DEFAULT_BLOCK_B,
@@ -188,35 +149,49 @@ def demm_xwT_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     bx, k = x.shape
-    o, g, n = values.shape
+    g, n, o = values.shape
     m = cfg.m
     assert k == g * m, (k, g, m)
     assert n == cfg.n_effective, (n, cfg)
-    block_b = min(block_b, bx)
-    block_o = min(block_o, o)
-    # Ragged serving batches / output dims are zero-padded to the tile grid
-    # and sliced back after.
-    x = _pad_to(x, 0, block_b)
-    values = _pad_to(values, 0, block_o)
-    indices = _pad_to(indices, 0, block_o)
-    bxp, op = x.shape[0], values.shape[0]
-
-    grid = (bxp // block_b, op // block_o, g)
-    kernel = functools.partial(_xwT_kernel, m=m, n=n)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
+    block_b, block_o, chunk = xwT_grid(x.shape, values.shape, m, block_b,
+                                       block_o)
+    return pl.pallas_call(
+        functools.partial(_xwT_kernel, m=m, interpret=interpret),
+        grid=(bx // block_b, o // block_o, g // chunk),
         in_specs=[
-            pl.BlockSpec((block_b, m), lambda i, j, gg: (i, gg)),
-            pl.BlockSpec((block_o, 1, n), lambda i, j, gg: (j, gg, 0)),
-            pl.BlockSpec((block_o, 1, n), lambda i, j, gg: (j, gg, 0)),
+            pl.BlockSpec((block_b, chunk * m), lambda i, j, c: (i, c)),
+            pl.BlockSpec((chunk, n, block_o), lambda i, j, c: (c, 0, j)),
+            pl.BlockSpec((chunk, n, block_o), lambda i, j, c: (c, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, gg: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((bxp, op), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, c: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((bx, o), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="demm_xwT",
     )(x, values, indices)
-    return out[:bx, :o]
+
+
+# ---------------------------------------------------------------------------
+# C = A_sparse @ B (paper orientation)
+# ---------------------------------------------------------------------------
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("cfg", "block_r", "block_c", "interpret"),
+)
+def demm_spmm_pallas(
+    values: jax.Array,      # (G, N, R)
+    indices: jax.Array,     # (G, N, R) int32
+    b: jax.Array,           # (K, Cd), K = G * M
+    cfg: SparsityConfig,
+    *,
+    block_r: int = DEFAULT_BLOCK_R,
+    block_c: int = DEFAULT_BLOCK_C,
+    interpret: bool = False,
+) -> jax.Array:
+    """C = A @ B as ``(Bᵀ @ Aᵀ)ᵀ``: the dense operand is transposed, the
+    packed stream is consumed in place by the xwT kernel."""
+    return demm_xwT_pallas(b.T, values, indices, cfg, block_b=block_c,
+                           block_o=block_r, interpret=interpret).T

@@ -113,11 +113,17 @@ def demm_matmul_packed(x: jax.Array, pw: PackedWeight,
         return demm_matmul_packed(x, narrow_tier(pw), backend)
     if getattr(pw, "shard_axis", None) is not None:
         return _demm_matmul_sharded(x, pw, backend)
+    from repro.sharding import context as shctx
+
+    ctx = shctx.get_context()
+    if ctx is not None and ctx.tp > 1:
+        return _demm_matmul_out_sharded(x, pw, backend, ctx)
     if pw.layout == LAYOUT_BLOCK:
         if getattr(pw.values, "ndim", 4) != 4:
             raise ValueError(
-                f"demm_matmul_packed needs an unstacked (RB, A_max, block_r, "
-                f"Ne) block weight, got values of shape {pw.values.shape}")
+                f"demm_matmul_packed needs an unstacked (RB, A_max, Ne, "
+                f"block_r) block weight, got values of shape "
+                f"{pw.values.shape}")
         return demm_matmul_block(x, pw, backend)
     if pw.layout != LAYOUT_XWT:
         raise ValueError(
@@ -125,7 +131,7 @@ def demm_matmul_packed(x: jax.Array, pw: PackedWeight,
             f"{LAYOUTS}")
     if getattr(pw.values, "ndim", 3) != 3:
         raise ValueError(
-            f"demm_matmul_packed needs an unstacked (O, G, Ne) weight, got "
+            f"demm_matmul_packed needs an unstacked (G, Ne, O) weight, got "
             f"values of shape {pw.values.shape}; slice the stack axis first")
     if pw.qdtype is not None:
         return demm_matmul_xwT_q8(x, pw.values, pw.indices, pw.scales,
@@ -148,7 +154,6 @@ def _demm_matmul_sharded(x: jax.Array, pw: PackedWeight,
     sum-over-slices, so outputs are bitwise-comparable across the two paths
     up to float summation order.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.sparsity import shard_slice
@@ -181,12 +186,53 @@ def _demm_matmul_sharded(x: jax.Array, pw: PackedWeight,
 
     def local_fn(xl, *cl):
         pw_local = shard_slice(jax.tree_util.tree_unflatten(treedef, cl), 0)
-        y = demm_matmul_packed(xl, pw_local, backend)
+        with shctx.suspend():
+            y = demm_matmul_packed(xl, pw_local, backend)
         return jax.lax.psum(y, axis)
 
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(None, axis),) + (P(axis),) * len(children),
-                   out_specs=P(None, None), check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(None, axis),) + (P(axis),) * len(children),
+                       out_specs=P(None, None), check_vma=False)
+    return fn(x, *children)
+
+
+def _demm_matmul_out_sharded(x: jax.Array, pw: PackedWeight, backend: str,
+                             ctx) -> jax.Array:
+    """y = x @ W^T under a tensor-parallel mesh, for a weight that is not
+    shard-stacked: a shard_map island that splits the output dim (O for
+    ``xwT``, the row-block axis for ``block``) over the ``model`` axis —
+    the placement ``ShardingPlan`` gives column-parallel weights — and runs
+    the kernel on each device's local operands.  The compiler cannot
+    partition a Pallas kernel, so no packed matmul is left to it under a
+    TP mesh.  A weight placed otherwise is resliced at the island's edge
+    (a local slice, for a replicated one), so the result never depends on
+    placement; a weight whose output dim does not divide TP runs whole on
+    every device.  Activation rows stay split over the batch axes."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding import context as shctx
+
+    block = pw.layout == LAYOUT_BLOCK
+    units = pw.values.shape[0] if block else pw.out_features
+    split = "model" if units % ctx.tp == 0 else None
+    dp = ctx.dp_degree()
+    rows = ctx.batch_axes if dp > 1 and x.shape[0] % dp == 0 else None
+    children, treedef = jax.tree_util.tree_flatten(pw)
+    # block children lead with the row-block axis, xwT children end in O
+    specs = [P(split) if block else P(*[None] * (c.ndim - 1), split)
+             for c in children]
+    o, k = pw.dense_shape
+    local = (o // ctx.tp if split else o, k)
+
+    def local_fn(xl, *cl):
+        pw_local = jax.tree_util.tree_unflatten(treedef, cl).replace(
+            dense_shape=local)
+        with shctx.suspend():
+            return demm_matmul_packed(xl, pw_local, backend)
+
+    fn = jax.shard_map(local_fn, mesh=ctx.mesh,
+                       in_specs=(P(rows, None), *specs),
+                       out_specs=P(rows, split), check_vma=False)
     return fn(x, *children)
 
 
@@ -241,7 +287,7 @@ def _dispatch_xwT(x, values, indices, cfg, w_shape, backend, shards=1):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def demm_matmul_xwT(x, values, indices, cfg: SparsityConfig, w_shape,
                     backend: str = "reference", shards: int = 1):
-    """y = x @ W_sparseᵀ; x (B, K), W packed (O, G, Ne) for dense (O, K).
+    """y = x @ W_sparseᵀ; x (B, K), W packed (G, Ne, O) for dense (O, K).
     ``shards`` > 1 tags the shard-local problem of a renumbered row-parallel
     weight for ``backend="auto"`` cache keying; the math is unchanged."""
     return _dispatch_xwT(x, values, indices, cfg, w_shape, backend, shards)
@@ -253,16 +299,15 @@ def _xwT_fwd(x, values, indices, cfg, w_shape, backend, shards=1):
 
 
 def _xwT_bwd(cfg, w_shape, backend, shards, res, dy):
+    from repro.sparsetrain.vjp import gather_xwT_slots
+
     x, values, indices = res
     o, k = w_shape
-    m = cfg.m
-    g = k // m
     w = unpack(values, indices, cfg, (o, k))                 # (O, K)
     dx = jnp.dot(dy, w.astype(dy.dtype))                      # (B, K)
     # dW = dyᵀ @ x, needed only at the packed coordinates.
     dw = jnp.dot(dy.T.astype(jnp.float32), x.astype(jnp.float32))  # (O, K)
-    dw_g = dw.reshape(o, g, m)
-    dvalues = jnp.take_along_axis(dw_g, indices, axis=-1).astype(values.dtype)
+    dvalues = gather_xwT_slots(dw, indices, cfg.m).astype(values.dtype)
     # Padded slots (value 0 at index 0) must not accumulate gradient, or they
     # would densify the pattern.
     dvalues = jnp.where(values != 0, dvalues, jnp.zeros((), values.dtype))
@@ -274,8 +319,8 @@ demm_matmul_xwT.defvjp(_xwT_fwd, _xwT_bwd)
 
 def demm_matmul_xwT_q8(x, values, indices, scales, cfg: SparsityConfig,
                        w_shape, backend: str = "reference", shards: int = 1):
-    """y = x @ W_q8ᵀ; int8 values (O, G, Ne) + scales (O,) per output row or
-    (O, G) per group (``repro.quant`` granularities).
+    """y = x @ W_q8ᵀ; int8 values (G, Ne, O) + scales (O,) per output row or
+    (G, O) per group (``repro.quant`` granularities).
 
     Carries a custom_vjp (``repro.sparsetrain.vjp``): dx and dL/dscales are
     exact; the int8 values are not a differentiable parameterization —

@@ -47,7 +47,7 @@ def block_spmm_ref(active_groups, values, indices, b, cfg: SparsityConfig,
 
 def xwT_q8_ref(x: jax.Array, values: jax.Array, indices: jax.Array,
                scales: jax.Array, cfg: SparsityConfig, w_shape) -> jax.Array:
-    """y = x @ W_q8ᵀ with per-output-row (O,) or per-group (O, G) scales:
+    """y = x @ W_q8ᵀ with per-output-row (O,) or per-group (G, O) scales:
     dequant + float ref."""
     vals = values.astype(jnp.float32) * expand_scales(scales, values)
     return xwT_ref(x, vals, indices, cfg, w_shape)
@@ -57,5 +57,5 @@ def block_spmm_q8_ref(active_groups, values, indices, scales, b,
                       cfg: SparsityConfig, r: int) -> jax.Array:
     """Two-level block oracle with per-(row-block, group, row) scales
     (RB, A_max, block_r): dequant + float ref."""
-    vals = values.astype(jnp.float32) * scales[..., None]
+    vals = values.astype(jnp.float32) * expand_scales(scales, values)
     return block_spmm_ref(active_groups, vals, indices, b, cfg, r)

@@ -38,13 +38,14 @@ def _pack_sparse_linear(node, cfg, layout=LAYOUT_XWT, *, block_r=None,
         return pack_block_stacked(w, cfg, block_r=block_r, a_max=a_max)
     if w.ndim == 2:
         return sl.pack_params(node, cfg)
-    # layer-stacked (L, ..., O, K): pack rows flat, restore the stack dims
+    # layer-stacked (L, ..., O, K): pack every slice, restore the stack dims
     lead = w.shape[:-2]
     o, k = w.shape[-2], w.shape[-1]
-    pw = sl.pack_params({"w": w.reshape(-1, k)}, cfg)
+    pw = jax.vmap(lambda wi: sl.pack_params({"w": wi}, cfg))(
+        w.reshape(-1, o, k))
     return PackedWeight(
-        pw.values.reshape(*lead, o, *pw.values.shape[1:]),
-        pw.indices.reshape(*lead, o, *pw.indices.shape[1:]),
+        pw.values.reshape(*lead, *pw.values.shape[1:]),
+        pw.indices.reshape(*lead, *pw.indices.shape[1:]),
         cfg=cfg, dense_shape=(o, k), layout=pw.layout)
 
 
@@ -85,6 +86,27 @@ def pack_tree(params, layout: str = LAYOUT_XWT, *, block_r=None, a_max=None,
                              granularity=granularity)
                 for k, v in params.items()}
     return params
+
+
+def init_packed(model, key, **pack_kw):
+    """``pack_tree(model.init(key), **pack_kw)`` as one jitted program.
+
+    Decoder models pack each layer inside their layer-init loop, so the
+    dense float32 tree never exists whole on the device: at stablelm_3b
+    width it alone nearly fills a 16 GB chip, and packing it afterwards
+    does not fit.  The result equals packing the dense init."""
+    from functools import partial
+
+    from repro.models.transformer import DecoderLM
+
+    pack = partial(pack_tree, **pack_kw)
+
+    def build(k):
+        if isinstance(model, DecoderLM):
+            return pack(model.init(k, layer_fn=pack))
+        return pack(model.init(k))
+
+    return jax.jit(build)(key)
 
 
 def pack_tree_shapes(model, param_shapes, layout: str = LAYOUT_XWT, *,

@@ -1,4 +1,5 @@
-"""Batched serving driver (reduced configs on CPU; production via dry-run).
+"""Batched serving driver: a reduced config by default, the published
+widths with ``--full`` (on a TPU chip; ``chip_smoke.py`` drives that path).
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3_1b --requests 8 \
         --packed --layout block --quantize int8 --backend auto --autotune
@@ -28,6 +29,10 @@ full-tier dispatch; ``--temperature``/``--top-k`` select replay-safe
 coupled sampling (token streams are identical with and without
 speculation, preemption included).
 
+Without ``--ckpt-dir``, ``--packed`` builds the packed tree in one jitted
+init-and-pack program (``launch.pack_tree.init_packed``): the dense float32
+tree of a full-width model never exists whole on the device.
+
 ``--ckpt-dir`` restores trained params from a ``launch/train.py``
 checkpoint before packing — the serve half of the dense → prune →
 train/QAT → pack → serve pipeline (a ``--sparsify`` run's final checkpoint
@@ -54,7 +59,8 @@ import numpy as np
 from repro import obs
 from repro.configs.base import ARCH_IDS, get_arch
 from repro.core.sparse_linear import ExecPolicy
-from repro.launch.pack_tree import pack_tree
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.pack_tree import init_packed, pack_tree
 from repro.models.families import build_model
 from repro.serve import Request, ServeConfig, make_engine
 
@@ -89,7 +95,8 @@ def run_serve(model, params, vocab_size: int, *, packed: bool = True,
               prefill_chunk: int = 32, scheduler: str = "fcfs",
               trace_replay=None, plan=None, replicas: int = 1,
               spec_draft=None, spec_gamma: int = 4,
-              temperature: float = 0.0, top_k: int = 0, recorder=None):
+              temperature: float = 0.0, top_k: int = 0, recorder=None,
+              sampler=None):
     """Pack (optionally) and serve ``requests`` random prompts; returns the
     drained engine.  The reusable core of ``main()`` — the end-to-end
     examples call this directly with their own trained params.
@@ -112,6 +119,10 @@ def run_serve(model, params, vocab_size: int, *, packed: bool = True,
     full-tier dispatch.  ``temperature``/``top_k`` select replay-safe
     coupled sampling (0 = greedy); the token stream is identical with and
     without speculation.
+
+    ``sampler`` (an object with ``sample(logits, uid, pos) -> int``)
+    replaces each engine's token sampler, e.g. to record the logits the
+    engine produced or to force a given token stream.
     """
     spec = None
     if spec_draft is not None:
@@ -141,6 +152,9 @@ def run_serve(model, params, vocab_size: int, *, packed: bool = True,
     engine = make_engine(model, params, serve_cfg, policy=policy,
                          autotune=autotune and packed, replicas=replicas,
                          spec=spec, recorder=recorder)
+    if sampler is not None:
+        for e in getattr(engine, "replicas", [engine]):
+            e.sampler = sampler
     if trace_replay:
         rows = _load_trace(trace_replay)
         t0 = time.time()
@@ -308,6 +322,7 @@ def main():
                          "proves the stall->dump path); exits nonzero if no "
                          "dump appears")
     args = ap.parse_args()
+    use_compile_cache()
     if args.autotune:
         args.backend = "auto"
     if args.tp < 1 or args.pp < 1 or args.replicas < 1:
@@ -365,7 +380,12 @@ def main():
         n, m = parse_tier(args.sparsity)
         cfg = _dc.replace(cfg, sparsity=SparsityConfig(n, m, 1))
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    if args.packed and not args.ckpt_dir:
+        params = init_packed(model, jax.random.PRNGKey(0),
+                             layout=args.layout, quantize=args.quantize,
+                             granularity=args.quantize_granularity)
+    else:
+        params = model.init(jax.random.PRNGKey(0))
     if args.ckpt_dir:
         from repro.train import checkpoint as ckpt
 

@@ -45,6 +45,7 @@ from repro.models.families import build_model
 from repro.optim import adamw
 from repro.train.fault_tolerance import SupervisorConfig, TrainingSupervisor
 from repro.train.train_loop import make_train_step
+from repro.launch.compile_cache import use_compile_cache
 
 
 def add_frontend_inputs(cfg, batch, rng):
@@ -123,6 +124,7 @@ def main():
                          "exceeds this multiple of the EWMA step interval "
                          "(floored at 1s)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.qat and not args.sparsify:
         ap.error("--qat rides the sparsify training path; add --sparsify")
     if args.reduced and args.full:

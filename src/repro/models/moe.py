@@ -112,7 +112,6 @@ def _apply_moe_local(params, x, cfg: MoEConfig, *, policy=None, capacity: int | 
 
 def _apply_moe_ep(params, x, cfg: MoEConfig, ctx, *, policy, capacity):
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, t, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
@@ -173,13 +172,13 @@ def _apply_moe_ep(params, x, cfg: MoEConfig, ctx, *, policy, capacity):
         y = y.at[stok].add(gathered.astype(jnp.float32) * sg[:, None])
         return y.reshape(bl, tl, d).astype(x_loc.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None),
                   P(dp, None, None)),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(params["router"]["w"], params["w_gate"], params["w_up"],
       params["w_down"], x)
     return y, aux

@@ -190,13 +190,19 @@ def _remat(fn, cfg: ArchConfig):
 class DecoderLM:
     cfg: ArchConfig
 
-    def init(self, key):
+    def init(self, key, layer_fn=None):
+        """Random params.  Layers are built one at a time (``lax.map``),
+        so only one layer's dense temporaries are live; ``layer_fn``
+        transforms each layer inside that loop (e.g. ``launch.pack_tree``
+        packing it), so a full-width model can be built in its served form
+        without its dense tree ever existing whole."""
         cfg = self.cfg
         dtype = dtype_of(cfg.param_dtype)
+        layer_fn = layer_fn or (lambda p: p)
         k_e, k_u, k_l, k_p = jax.random.split(key, 4)
         layer_keys = jax.random.split(k_l, cfg.num_layers)
-        layers = jax.vmap(
-            lambda k: init_tblock(k, cfg, dtype=dtype))(layer_keys)
+        layers = jax.lax.map(
+            lambda k: layer_fn(init_tblock(k, cfg, dtype=dtype)), layer_keys)
         params = {
             "embed": init_embedding(k_e, cfg.padded_vocab, cfg.d_model, dtype),
             "unembed": init_embedding(k_u, cfg.padded_vocab, cfg.d_model, dtype),

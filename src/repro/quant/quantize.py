@@ -6,14 +6,14 @@ Granularity follows the packed layout (DESIGN.md §10):
   The row is the reduction unit of the serving matmul ``y = x @ Wᵀ``, so a
   per-row scale folds into the kernel as a single multiply on the (rows, M)
   scatter matrix.  ``granularity="per_group"`` refines this to one scale
-  per (row, M-group): ``scales (*stack, O, G)`` — each group's Ne values
+  per (M-group, row): ``scales (*stack, G, O)`` — each group's Ne values
   share one exponent, which matters exactly when a row mixes large and
-  small groups (the kernel cost is unchanged: the scatter tile of grid step
-  ``g`` scales by column ``g`` of the scales operand instead of column 0).
+  small groups (the kernel cost is unchanged: group ``g`` of a grid step
+  scales by row ``g`` of the scales operand instead of the single row).
 * ``block``  — one scale per (row-block, active-group slot, row):
   ``scales (*stack, RB, A_max, block_r)``.  Per-group scales are finer than
   per-row (each group's Ne values share one exponent) and line up with the
-  block kernel's (block_r, Ne) value tiles.
+  block kernel's (Ne, block_r) value tiles.
 
 Quantization is symmetric round-to-nearest: ``q = clip(round(v / s), ±127)``
 with ``s = amax / 127`` (data-free) or an observer-provided scale.  Padded
@@ -63,10 +63,11 @@ def _check_granularity(pw: PackedWeight, granularity: str):
 
 
 def _reduce_axes(pw: PackedWeight, granularity: str = "per_row"):
-    """Packed axes reduced away by one scale unit."""
+    """Packed axes reduced away by one scale unit: the Ne axis, plus the
+    group axis in front of it for per-row xwT scales."""
     if pw.layout == LAYOUT_BLOCK or granularity == "per_group":
-        return (-1,)
-    return (-2, -1)
+        return (-2,)
+    return (-3, -2)
 
 
 def amax_scales(pw: PackedWeight,
@@ -97,7 +98,7 @@ def quantize_packed(pw: PackedWeight, qdtype: str = QDTYPE_INT8, *,
     :func:`activation_calibration`); by default the cheap data-free
     :func:`amax_scales` pass is used.  ``granularity`` picks the scale unit
     for the xwT layout — ``per_row`` (``scales (*stack, O)``, the default)
-    or ``per_group`` (``(*stack, O, G)``); an observer's output shape wins
+    or ``per_group`` (``(*stack, G, O)``); an observer's output shape wins
     over ``granularity``.  Returns a new ``PackedWeight`` with int8
     ``values``, a float32 ``scales`` child, and the ``qdtype`` aux tag;
     ``indices``/``active_groups`` and all static aux are shared unchanged.
@@ -155,7 +156,7 @@ def _slot_columns(pw: PackedWeight) -> jax.Array:
         return (pw.active_groups[..., None, None] * m
                 + pw.indices).astype(jnp.int32)
     g = pw.groups
-    gids = jnp.arange(g, dtype=jnp.int32)[:, None]        # (G, 1)
+    gids = jnp.arange(g, dtype=jnp.int32)[:, None, None]  # (G, 1, 1)
     return (gids * m + pw.indices).astype(jnp.int32)
 
 

@@ -17,7 +17,10 @@ write one construction path for single-device, TP, PP, and (with
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
+
+import jax
 
 from repro.serve.protocol import Engine, EngineBase
 from repro.serve.router import ReplicaRouter, make_replicas
@@ -43,9 +46,13 @@ def make_engine(model, params, config, *, plan=None, policy=None,
       decode state.  Passing both ``plan`` and a policy that already
       carries a *different* plan is an error.
     * ``replicas`` — N > 1 wraps N engines (each with its own metrics
-      registry and decode state, sharing ``params``) in a round-robin
+      registry and decode state) in a round-robin
       :class:`~repro.serve.router.ReplicaRouter`; ``metrics`` must then be
       None (each replica owns a registry; the router merges snapshots).
+      Single-device replicas get a device each when there are enough
+      (:func:`replica_devices`): replica ``i`` holds its own copy of
+      ``params`` and its decode state on ``jax.devices()[i]``.  Otherwise
+      they share ``params`` on one device.
     * ``spec`` — optional :class:`~repro.spec.SpecConfig`: the engine
       drafts with the sparser-tier view of the same packed buffers and
       verifies in batched full-tier dispatches (DESIGN.md §15).  Requires
@@ -64,19 +71,25 @@ def make_engine(model, params, config, *, plan=None, policy=None,
                 "plan in one place")
         policy = policy.replace(plan=plan)
 
-    def build(m):
+    def build(m, device=None):
+        p, scope = params, contextlib.nullcontext()
+        if device is not None:
+            # the replica's params and everything its engine allocates
+            # (decode state, KV arena) live on its own device
+            p, scope = jax.device_put(params, device), jax.default_device(device)
         # dispatch on config type, paged imported lazily (repro.paged
         # imports repro.serve for the Request type)
         type_name = type(config).__name__
-        if type_name == "PagedServeConfig":
-            from repro.paged import PagedServeEngine
-            return PagedServeEngine(model, params, config, policy=policy,
-                                    autotune=autotune, metrics=m, spec=spec,
-                                    recorder=recorder)
-        if isinstance(config, ServeConfig):
-            return ServeEngine(model, params, config, policy=policy,
-                               autotune=autotune, metrics=m, spec=spec,
-                               recorder=recorder)
+        with scope:
+            if type_name == "PagedServeConfig":
+                from repro.paged import PagedServeEngine
+                return PagedServeEngine(model, p, config, policy=policy,
+                                        autotune=autotune, metrics=m,
+                                        spec=spec, recorder=recorder)
+            if isinstance(config, ServeConfig):
+                return ServeEngine(model, p, config, policy=policy,
+                                   autotune=autotune, metrics=m, spec=spec,
+                                   recorder=recorder)
         raise TypeError(
             f"make_engine: unknown config type {type(config).__name__!r} "
             "(expected ServeConfig or PagedServeConfig)")
@@ -86,5 +99,16 @@ def make_engine(model, params, config, *, plan=None, policy=None,
             raise ValueError(
                 "make_engine(replicas=N, metrics=...) is unsupported: each "
                 "replica owns a registry and the router merges snapshots")
-        return make_replicas(replicas, build)
+        return make_replicas(replicas, build,
+                             devices=replica_devices(replicas, policy.plan))
     return build(metrics)
+
+
+def replica_devices(replicas: int, plan=None):
+    """One device per replica when every replica is a single-device engine
+    and enough devices are visible; ``None`` (replicas share the default
+    device) otherwise."""
+    per_replica = 1 if plan is None else plan.tp * plan.pp
+    if per_replica == 1 and jax.device_count() >= replicas:
+        return jax.devices()[:replicas]
+    return None

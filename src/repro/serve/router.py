@@ -22,7 +22,7 @@ per-replica family, plus router-level gauges:
 
 from __future__ import annotations
 
-from typing import Callable, List
+from typing import Callable, List, Optional, Sequence
 
 from repro.obs.metrics import MetricsRegistry
 from repro.serve.protocol import EngineBase
@@ -164,10 +164,16 @@ class ReplicaRouter(EngineBase):
         return sum(depth(e) for e in self.replicas)
 
 
-def make_replicas(n: int, factory: Callable[[MetricsRegistry], object]
-                  ) -> ReplicaRouter:
+def make_replicas(n: int, factory: Callable[..., object],
+                  devices: Optional[Sequence] = None) -> ReplicaRouter:
     """Build N replicas through ``factory(metrics_registry)`` — the factory
     must pass the registry to the engine it builds (each replica gets its
     own, so the merged snapshot can label families per replica) — and wrap
-    them in a :class:`ReplicaRouter`."""
-    return ReplicaRouter([factory(MetricsRegistry()) for _ in range(n)])
+    them in a :class:`ReplicaRouter`.  With ``devices``, replica ``i`` is
+    built by ``factory(metrics_registry, devices[i])`` on that device."""
+    if devices is None:
+        return ReplicaRouter([factory(MetricsRegistry()) for _ in range(n)])
+    if len(devices) < n:
+        raise ValueError(f"{n} replicas need {n} devices, got {len(devices)}")
+    return ReplicaRouter([factory(MetricsRegistry(), devices[i])
+                          for i in range(n)])

@@ -32,7 +32,7 @@ from repro.core.treeutil import key_path_str as _path_str
 # weights are not matched by leaf-name regexes: PackedWeight nodes are
 # handled structurally (isinstance) in ``param_specs``, which classifies the
 # node's module path as col/row-parallel via the same rules and shards the
-# values/indices children by their known (O, G, Ne) geometry.
+# values/indices children by their known (G, Ne, O) geometry.
 
 def _rules():
     return [
@@ -66,14 +66,13 @@ def _rules():
 
 def _col_spec(ndim: int) -> P:
     """Column-parallel: output dim (axis 0 of (out, in) weights) sharded.
-    Packed sparse tensors (O, G, N) shard the same axis 0."""
+    (Packed weights are placed by ``_packed_spec``.)"""
     return P(*(["model"] + [None] * (ndim - 1)))
 
 
 def _row_spec(ndim: int) -> P:
-    """Row-parallel: contraction dim sharded.  Dense (out, in) -> axis 1;
-    packed (O, G, N) -> the group axis 1 (groups tile the contraction dim,
-    and choose_group aligned M to the shard size)."""
+    """Row-parallel: contraction dim sharded.  Dense (out, in) -> axis 1.
+    (Packed weights are placed by ``_packed_spec``.)"""
     if ndim == 1:
         return P(None)
     return P(*([None, "model"] + [None] * (ndim - 2)))
@@ -121,14 +120,14 @@ def linear_kind(path: str, **_kw) -> str:
 
 
 def _packed_spec(kind: str, extra: int) -> P:
-    """values/indices are (*stack, O, G, Ne): column-parallel shards the
+    """values/indices are (*stack, G, Ne, O): column-parallel shards the
     output axis O; row-parallel shards the group axis G (groups tile the
     contraction dim, and choose_group aligned M to the shard size); stack
     dims are replicated."""
     if kind == "col":
-        core = ["model", None, None]
+        core = [None, None, "model"]
     elif kind == "row":
-        core = [None, "model", None]
+        core = ["model", None, None]
     else:
         core = [None, None, None]
     return P(*([None] * extra + core))
@@ -136,7 +135,7 @@ def _packed_spec(kind: str, extra: int) -> P:
 
 def _block_packed_specs(kind: str, extra: int):
     """Specs for the block layout: values/indices are
-    (*stack, RB, A_max, block_r, Ne) and active_groups (*stack, RB, A_max).
+    (*stack, RB, A_max, Ne, block_r) and active_groups (*stack, RB, A_max).
     Column-parallel shards the row-block axis RB (row blocks tile the output
     dim, so each TP shard owns whole row blocks and their address streams).
     Row-parallel would shard the contraction dim, but the active-group ids
@@ -176,12 +175,12 @@ def packed_weight_specs(pw: PackedWeight, kind: str) -> PackedWeight:
     same PackedWeight container so spec/sharding trees mirror the params.
 
     Quantized nodes (``repro.quant``) shard the ``scales`` child alongside
-    ``values``: the scale axes are a prefix of the value axes (per output
+    ``values``: the scale axes are the value axes without Ne (per output
     row or per group for xwT, per row-block × group × row for block), so
-    column-parallel shards the same leading output axis; row-parallel
-    shards per-group xwT scales on their group axis (it tiles the
-    contraction dim exactly like the values' group axis) and leaves per-row
-    scales replicated (no group axis to split).
+    column-parallel shards the same output axis; row-parallel shards
+    per-group xwT scales on their group axis (it tiles the contraction dim
+    exactly like the values' group axis) and leaves per-row scales
+    replicated (no group axis to split).
 
     A renumbered shard-stacked node (``pw.shard_axis`` set) is placed on its
     own shard dim regardless of ``kind`` — the renumbering pass only ever
@@ -202,7 +201,7 @@ def packed_weight_specs(pw: PackedWeight, kind: str) -> PackedWeight:
     if pw.qdtype is not None:
         per_group = (getattr(pw.scales, "ndim", extra + 1) - extra) == 2
         if per_group:
-            core = {"col": ["model", None], "row": [None, "model"]}.get(
+            core = {"col": [None, "model"], "row": ["model", None]}.get(
                 kind, [None, None])
         else:
             core = ["model"] if kind == "col" else [None]
@@ -221,7 +220,7 @@ def _param_specs_impl(params, *, attn_kv_replicated: bool = False,
     Handles layer stacking: rule specs are defined for the *unstacked*
     2-D/3-D weights; extra leading axes (scan stacking) are replicated.
     PackedWeight nodes are handled structurally: the module path picks
-    col/row-parallel and the (O, G, Ne) geometry places the axes.
+    col/row-parallel and the (G, Ne, O) geometry places the axes.
 
     ``attn_kv_replicated``: for archs whose KV head count does not divide
     TP (but whose Q heads do), K/V projection weights are replicated so the

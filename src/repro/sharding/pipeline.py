@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -78,10 +77,10 @@ def pipeline_apply(
         return outputs
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)
 
 
@@ -167,8 +166,8 @@ def pipeline_apply_stateful(
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
     sspec = jax.tree.map(lambda _: P(axis), stage_state)
     aspec = jax.tree.map(lambda _: P(), aux)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspec, sspec, P(), aspec), out_specs=(P(), sspec),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, stage_state, x, aux)

@@ -55,19 +55,28 @@ def _dw(dy: jax.Array, x: jax.Array) -> jax.Array:
     return jnp.dot(dy.T.astype(jnp.float32), x.astype(jnp.float32))
 
 
+def gather_xwT_slots(dw: jax.Array, indices: jax.Array, m: int) -> jax.Array:
+    """Gather the (O, K) dense cotangent at every xwT slot: result
+    (G, Ne, O) aligned with the lane-major packed values."""
+    o, k = dw.shape
+    dw_g = dw.T.reshape(k // m, m, o)                          # (G, M, O)
+    return jnp.take_along_axis(dw_g, indices, axis=1)          # (G, Ne, O)
+
+
 def _gather_block_slots(dw: jax.Array, indices: jax.Array,
                         active_groups: jax.Array, m: int) -> jax.Array:
     """Gather the (O, K) dense cotangent at every block-layout slot:
-    result (RB, A_max, block_r, Ne) aligned with the packed values."""
-    rb, a_max, block_r, _ne = indices.shape
+    result (RB, A_max, Ne, block_r) aligned with the packed values."""
+    rb, a_max, _ne, block_r = indices.shape
     o = rb * block_r
     g = dw.shape[1] // m
     assert dw.shape[0] == o, (dw.shape, indices.shape)
-    dw_g = jnp.swapaxes(dw.reshape(rb, block_r, g, m), 1, 2)   # (RB,G,br,M)
+    dw_g = jnp.transpose(dw.reshape(rb, block_r, g, m),
+                         (0, 2, 3, 1))                         # (RB,G,M,br)
     sel = jnp.take_along_axis(
         dw_g, active_groups[:, :, None, None].astype(jnp.int32), axis=1
-    )                                                          # (RB,A,br,M)
-    return jnp.take_along_axis(sel, indices, axis=-1)          # (RB,A,br,Ne)
+    )                                                          # (RB,A,M,br)
+    return jnp.take_along_axis(sel, indices, axis=2)           # (RB,A,Ne,br)
 
 
 # ---------------------------------------------------------------------------
@@ -127,16 +136,14 @@ def _q8_fwd(x, values, indices, scales, cfg, w_shape, backend, params):
 def _q8_bwd(cfg, w_shape, backend, params, res, dy):
     x, values, indices, scales = res
     o, k = w_shape
-    g = k // cfg.m
     vals_f = values.astype(jnp.float32)
     deq = vals_f * expand_scales(scales, values)
     w = unpack(deq, indices, cfg, (o, k))
     dx = jnp.dot(dy.astype(jnp.float32), w)
-    dslot = jnp.take_along_axis(_dw(dy, x).reshape(o, g, cfg.m), indices,
-                                axis=-1)                       # (O, G, Ne)
+    dslot = gather_xwT_slots(_dw(dy, x), indices, cfg.m)       # (G, Ne, O)
     # dL/ds = Σ over the slots sharing the scale of dW[slot] · int_value
     # (padded slots have int_value 0 and drop out automatically).
-    axes = (-1,) if scales.ndim == values.ndim - 1 else (-2, -1)
+    axes = (-2,) if scales.ndim == values.ndim - 1 else (-3, -2)
     dscales = jnp.sum(dslot * vals_f, axis=axes).astype(scales.dtype)
     return dx.astype(x.dtype), None, None, dscales
 
@@ -168,11 +175,11 @@ def _block_q8_bwd(cfg, w_shape, backend, params, res, dy):
     x, values, indices, active_groups, scales = res
     o, k = w_shape
     vals_f = values.astype(jnp.float32)
-    deq = vals_f * scales[..., None]
+    deq = vals_f * expand_scales(scales, values)
     w = unpack_block(active_groups, deq, indices, cfg, (o, k))
     dx = jnp.dot(dy.astype(jnp.float32), w)
     dslot = _gather_block_slots(_dw(dy, x), indices, active_groups, cfg.m)
-    dscales = jnp.sum(dslot * vals_f, axis=-1).astype(scales.dtype)
+    dscales = jnp.sum(dslot * vals_f, axis=-2).astype(scales.dtype)
     return dx.astype(x.dtype), None, None, None, dscales
 
 

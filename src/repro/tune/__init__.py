@@ -151,11 +151,12 @@ def autotune_packed_tree(params, batch: int, dtype=None, *,
             return
         quant = pw.qdtype is not None
         vals, idxs, scls = pw.values, pw.indices, pw.scales
-        if vals.ndim > 3:   # layer-stacked: tune one slice
-            vals = vals.reshape(-1, *vals.shape[-2:])[:o]
-            idxs = idxs.reshape(-1, *idxs.shape[-2:])[:o]
+        nstack = len(pw.stack_dims)
+        if nstack:   # layer-stacked: tune one slice
+            vals = vals.reshape(-1, *vals.shape[nstack:])[0]
+            idxs = idxs.reshape(-1, *idxs.shape[nstack:])[0]
             if quant:
-                scls = scls.reshape(-1)[:o]
+                scls = scls.reshape(-1, *scls.shape[nstack:])[0]
         p = Problem.for_xwT((batch, k), (o, k), pw.cfg, dtype,
                             quantized=quant, shards=pw.shards)
         key = problem_key(p)

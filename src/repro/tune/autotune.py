@@ -243,14 +243,21 @@ def _autotune(problem: Problem,
     cands = enumerate_candidates(problem)
     keep = prune_candidates(problem, cands, vmem_budget=vmem_budget,
                             max_measure=max_measure)
-    measure_only = {v.name for v in variants_for(
-        problem.op, problem, include_measure_only=True) if v.measure_only}
+    variants = variants_for(problem.op, problem, include_measure_only=True)
+    measure_only = {v.name for v in variants if v.measure_only}
+    defaults = {v.name: v.default_params(problem) for v in variants}
     for c in keep:
         try:
             c.measured_s = measure(make_thunk(c), warmup=warmup, iters=iters)
             c.status = "measured"
             measurements.inc()
         except Exception as e:  # noqa: BLE001 — an unmeasurable candidate
+            # A kernel that fails at its own default tiles on the chip is a
+            # broken kernel, not a slow candidate: recording it would
+            # quietly serve the reference path instead.
+            if (problem.platform == "tpu" and c.params
+                    and c.params == defaults.get(c.backend)):
+                raise
             c.status = "error"  # (e.g. unsupported tiling) is skipped, not fatal
             c.note = f"{type(e).__name__}: {e}"[:200]
         # one trace event per candidate: the autotune audit trail a tuned

@@ -22,11 +22,12 @@ Ops and uniform signatures
 ``xwT_block`` : call(x, values, indices, active_groups, cfg, w_shape,
                 **params) -> (B, O) — the two-level block layout packed ahead
                 of time by ``core.sparsity.pack_block`` (values/indices
-                (RB, A_max, block_r, Ne) + active_groups (RB, A_max)), fully
+                (RB, A_max, Ne, block_r) + active_groups (RB, A_max)), fully
                 dispatchable under jit (no host repacking).
 ``xwT_q8``    : call(x, values, indices, scales, cfg, w_shape, **params)
-                -> (B, O) — int8 values + per-output-row scales (O,)
-                (repro.quant); kernels dequantize in-register (w8a16).
+                -> (B, O) — int8 values + per-output-row scales (O,) or
+                per-group scales (G, O) (repro.quant); kernels dequantize
+                in-register (w8a16).
 ``xwT_block_q8``: call(x, values, indices, active_groups, scales, cfg,
                 w_shape, **params) -> (B, O) — the quantized two-level
                 layout, scales (RB, A_max, block_r).
@@ -222,7 +223,12 @@ _INTERPRET_FLOP_LIMIT = 2 ** 26
 def _register_builtin_variants():
     # Imported lazily so `repro.tune.registry` never forces Pallas at import.
     from repro.kernels import ref as kref
-    from repro.kernels.demm_spmm import demm_spmm_pallas, demm_xwT_pallas
+    from repro.kernels.demm_spmm import (LANES, SUBLANES, demm_spmm_pallas,
+                                         demm_xwT_pallas, fit_tile)
+
+    def _legal_tiles(dim: int, lo: int, hi: int, align: int):
+        return tuple(dict.fromkeys(
+            fit_tile(dim, c, align) for c in _pow2_candidates(dim, lo, hi)))
 
     def xwT_ref_call(x, values, indices, cfg, w_shape, **_):
         return kref.xwT_ref(x, values, indices, cfg, w_shape)
@@ -233,13 +239,16 @@ def _register_builtin_variants():
                                block_o=block_o, interpret=interpret)
 
     def xwT_tiles(p: Problem):
+        # The kernel rounds a request down to a legal TPU tile (fit_tile),
+        # so only requests that survive that rounding are distinct.
         return {
-            "block_b": _pow2_candidates(p.rows, 8, 512),
-            "block_o": _pow2_candidates(p.out, 8, 512),
+            "block_b": _legal_tiles(p.rows, 8, 512, SUBLANES),
+            "block_o": _legal_tiles(p.out, 128, 512, LANES),
         }
 
     def xwT_defaults(p: Problem):
-        return {"block_b": min(128, p.rows), "block_o": min(128, p.out)}
+        return {"block_b": fit_tile(p.rows, 128, SUBLANES),
+                "block_o": fit_tile(p.out, 128, LANES)}
 
     register_variant(KernelVariant(
         op="xwT", name="reference", call=xwT_ref_call,
@@ -269,12 +278,13 @@ def _register_builtin_variants():
 
     def spmm_tiles(p: Problem):
         return {
-            "block_r": _pow2_candidates(p.out, 8, 512),
-            "block_c": _pow2_candidates(p.rows, 8, 512),
+            "block_r": _legal_tiles(p.out, 128, 512, LANES),
+            "block_c": _legal_tiles(p.rows, 8, 512, SUBLANES),
         }
 
     def spmm_defaults(p: Problem):
-        return {"block_r": min(128, p.out), "block_c": min(256, p.rows)}
+        return {"block_r": fit_tile(p.out, 128, LANES),
+                "block_c": fit_tile(p.rows, 256, SUBLANES)}
 
     register_variant(KernelVariant(
         op="spmm", name="reference", call=spmm_ref_call,
@@ -294,7 +304,7 @@ def _register_builtin_variants():
         supported=lambda p: p.dense_flops <= _INTERPRET_FLOP_LIMIT,
         description="Pallas kernel in interpret mode (CPU checks)"))
 
-    def spmm_block_call(values, indices, b, cfg, a_shape, *,
+    def spmm_block_call(values, indices, b, cfg, a_shape, *, interpret,
                         block_r=128, cd_block=256, **_):
         # Host-side repack into the two-level block-sparse format: only
         # callable on concrete arrays (measure_only), never under jit.
@@ -311,14 +321,12 @@ def _register_builtin_variants():
                              f"{r} % {block_r}")
         dense = np.asarray(unpack(values, indices, cfg, tuple(a_shape)))
         ag, vals, idxs, _ = pack_block_sparse(dense, cfg, block_r=block_r)
-        interp = current_platform() != "tpu"
         return demm_block_spmm_pallas(
             jax.numpy.asarray(ag), jax.numpy.asarray(vals),
             jax.numpy.asarray(idxs), b, cfg, r=r, cd_block=cd_block,
-            interpret=interp)
+            interpret=interpret)
 
-    register_variant(KernelVariant(
-        op="spmm", name="block_spmm", call=spmm_block_call,
+    block_spmm_params = dict(
         param_space=lambda p: {
             "block_r": tuple(c for c in _pow2_candidates(p.out, 8, 256)
                              if p.out % c == 0),
@@ -331,12 +339,20 @@ def _register_builtin_variants():
             "cd_block": max((c for c in _pow2_candidates(p.rows, 8, 256)
                              if p.rows % c == 0), default=p.rows),
         },
-        supported=lambda p: (p.platform == "tpu"
-                             or p.dense_flops <= _INTERPRET_FLOP_LIMIT),
-        measure_only=True,
+        measure_only=True)
+    register_variant(KernelVariant(
+        op="spmm", name="block_spmm",
+        call=lambda *a, **kw: spmm_block_call(*a, interpret=False, **kw),
+        supported=lambda p: p.platform == "tpu",
         description="scalar-prefetch block-gather kernel (host repack of the "
                     "flat spmm packing; ahead-of-time conversion dispatches "
-                    "through the xwT_block op instead)"))
+                    "through the xwT_block op instead)", **block_spmm_params))
+    register_variant(KernelVariant(
+        op="spmm", name="block_spmm_interpret",
+        call=lambda *a, **kw: spmm_block_call(*a, interpret=True, **kw),
+        supported=lambda p: p.dense_flops <= _INTERPRET_FLOP_LIMIT,
+        description="block-gather kernel in interpret mode (CPU checks)",
+        **block_spmm_params))
 
     # ---- xwT_block: the two-level AOT block layout (serving orientation) --
     # Operands come pre-packed by core.sparsity.pack_block, so both variants
@@ -350,27 +366,17 @@ def _register_builtin_variants():
 
     def xwT_block_pallas_call(x, values, indices, active_groups, cfg,
                               w_shape, *, interpret, cd_block=256, **_):
-        from repro.kernels.demm_block_spmm import demm_block_spmm_pallas
+        from repro.kernels.demm_block_spmm import demm_block_xwT_pallas
 
-        o, _k = w_shape
-        b = x.T                                   # (K, B): paper orientation
-        cd = b.shape[1]
-        cd_block = min(cd_block, cd)
-        if cd % cd_block:
-            cd_block = cd                         # ragged batch: one tile
-        return demm_block_spmm_pallas(active_groups, values, indices, b, cfg,
-                                      r=int(o), cd_block=int(cd_block),
-                                      interpret=interpret).T
+        return demm_block_xwT_pallas(x, values, indices, active_groups, cfg,
+                                     cd_block=int(cd_block),
+                                     interpret=interpret)
 
     def xwT_block_tiles(p: Problem):
-        return {"cd_block": tuple(
-            c for c in _pow2_candidates(p.rows, 8, 256) if p.rows % c == 0
-        ) or (p.rows,)}
+        return {"cd_block": _legal_tiles(p.rows, 8, 256, SUBLANES)}
 
     def xwT_block_defaults(p: Problem):
-        return {"cd_block": max(
-            (c for c in _pow2_candidates(p.rows, 8, 256) if p.rows % c == 0),
-            default=p.rows)}
+        return {"cd_block": fit_tile(p.rows, 256, SUBLANES)}
 
     register_variant(KernelVariant(
         op="xwT_block", name="reference", call=xwT_block_ref_call,
@@ -380,17 +386,24 @@ def _register_builtin_variants():
     register_variant(KernelVariant(
         op="xwT_block", name="block_spmm",
         call=lambda *a, **kw: xwT_block_pallas_call(
-            *a, interpret=current_platform() != "tpu", **kw),
+            *a, interpret=False, **kw),
         param_space=xwT_block_tiles, default_params=xwT_block_defaults,
-        supported=lambda p: (p.platform == "tpu"
-                             or p.dense_flops <= _INTERPRET_FLOP_LIMIT),
+        supported=lambda p: p.platform == "tpu",
         description="scalar-prefetch block-gather Pallas kernel over the "
-                    "ahead-of-time two-level packing (interpret on CPU)"))
+                    "ahead-of-time two-level packing"))
+    register_variant(KernelVariant(
+        op="xwT_block", name="block_spmm_interpret",
+        call=lambda *a, **kw: xwT_block_pallas_call(
+            *a, interpret=True, **kw),
+        param_space=xwT_block_tiles, default_params=xwT_block_defaults,
+        supported=lambda p: p.dense_flops <= _INTERPRET_FLOP_LIMIT,
+        description="block-gather Pallas kernel in interpret mode (CPU "
+                    "checks)"))
 
     # ---- int8 quantized ops (repro.quant): w8a16 dequant-in-register ------
     # Variant names mirror the float ops so heuristic_default's platform
     # preferences ("pallas" / "block_spmm" on TPU) apply unchanged.
-    from repro.kernels.demm_q8 import (demm_block_spmm_q8_pallas,
+    from repro.kernels.demm_q8 import (demm_block_xwT_q8_pallas,
                                        demm_xwT_q8_pallas)
 
     def xwT_q8_ref_call(x, values, indices, scales, cfg, w_shape, **_):
@@ -430,16 +443,9 @@ def _register_builtin_variants():
     def xwT_block_q8_pallas_call(x, values, indices, active_groups, scales,
                                  cfg, w_shape, *, interpret, cd_block=256,
                                  **_):
-        o, _k = w_shape
-        b = x.T                                   # (K, B): paper orientation
-        cd = b.shape[1]
-        cd_block = min(cd_block, cd)
-        if cd % cd_block:
-            cd_block = cd                         # ragged batch: one tile
-        return demm_block_spmm_q8_pallas(active_groups, values, indices,
-                                         scales, b, cfg, r=int(o),
-                                         cd_block=int(cd_block),
-                                         interpret=interpret).T
+        return demm_block_xwT_q8_pallas(x, values, indices, active_groups,
+                                        scales, cfg, cd_block=int(cd_block),
+                                        interpret=interpret)
 
     register_variant(KernelVariant(
         op="xwT_block_q8", name="reference", call=xwT_block_q8_ref_call,
@@ -449,12 +455,19 @@ def _register_builtin_variants():
     register_variant(KernelVariant(
         op="xwT_block_q8", name="block_spmm",
         call=lambda *a, **kw: xwT_block_q8_pallas_call(
-            *a, interpret=current_platform() != "tpu", **kw),
+            *a, interpret=False, **kw),
         param_space=xwT_block_tiles, default_params=xwT_block_defaults,
-        supported=lambda p: (p.platform == "tpu"
-                             or p.dense_flops <= _INTERPRET_FLOP_LIMIT),
+        supported=lambda p: p.platform == "tpu",
         description="scalar-prefetch block-gather Pallas kernel over the "
-                    "quantized two-level packing (w8a16; interpret on CPU)"))
+                    "quantized two-level packing (w8a16)"))
+    register_variant(KernelVariant(
+        op="xwT_block_q8", name="block_spmm_interpret",
+        call=lambda *a, **kw: xwT_block_q8_pallas_call(
+            *a, interpret=True, **kw),
+        param_space=xwT_block_tiles, default_params=xwT_block_defaults,
+        supported=lambda p: p.dense_flops <= _INTERPRET_FLOP_LIMIT,
+        description="quantized block-gather Pallas kernel in interpret mode "
+                    "(CPU checks)"))
 
 
 _register_builtin_variants()

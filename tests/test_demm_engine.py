@@ -155,7 +155,7 @@ def test_sparse_linear_k_reconfiguration_survives_pack():
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 64))
     pw = sl.pack_params(params, cfg)
     assert pw.cfg == cfg and pw.cfg.k == 2
-    assert pw.values.shape[-1] == cfg.n_effective == 4
+    assert pw.values.shape[-2] == cfg.n_effective == 4
     y_masked = sl.apply_masked(params, x, cfg)
     y_packed = sl.apply(pw, x, ExecPolicy(mode="packed"))
     np.testing.assert_allclose(np.asarray(y_masked), np.asarray(y_packed),

@@ -12,6 +12,7 @@ def test_tp_dp_train_step_matches_single_device():
     result numerically (same params, same batch)."""
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_test_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import get_arch
 from repro.models.families import build_model
@@ -35,7 +36,7 @@ step = make_train_step(model, opt_cfg, num_microbatches=2)
 p_ref, _, m_ref = jax.jit(step)(params, opt, batch, 0)
 
 # distributed
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 ctx = shctx.make_context(mesh, num_kv_heads=cfg.num_kv_heads)
 pspecs = ShardingPlan().param_specs(params)
 pshard = shardings_for(mesh, pspecs)
@@ -65,6 +66,7 @@ print("TP/DP train step matches single-device")
 def test_moe_expert_parallel_matches_local():
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_test_mesh
 from jax.sharding import PartitionSpec as P
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
@@ -76,7 +78,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64))
 
 y_ref, aux_ref = moe_mod._apply_moe_local(params, x, cfg, capacity=64)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 ctx = shctx.make_context(mesh, num_kv_heads=16)
 # drop-free capacities on both paths -> results must agree exactly
 with shctx.use_mesh(ctx):
@@ -91,6 +93,7 @@ print("MoE EP matches local dispatch")
 def test_pipeline_parallel_matches_sequential():
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_test_mesh
 from repro.sharding.pipeline import pipeline_apply
 
 n_stages, num_mb, mb, d = 8, 4, 2, 16
@@ -107,7 +110,7 @@ y_ref = x
 for i in range(n_stages):
     y_ref = jax.vmap(lambda xx: stage_fn({"w": stage_params["w"][i]}, xx))(y_ref)
 
-mesh = jax.make_mesh((8,), ("pipe",))
+mesh = make_test_mesh((8,), ("pipe",))
 y_pipe = jax.jit(lambda p, x: pipeline_apply(stage_fn, p, x, mesh))(
     stage_params, x)
 np.testing.assert_allclose(np.asarray(y_pipe), np.asarray(y_ref),
@@ -124,12 +127,13 @@ print("pipeline == sequential, grads finite")
 def test_elastic_restore_to_smaller_mesh(tmp_path):
     run_with_devices(f"""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_test_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.train import checkpoint as ckpt
 from repro.train.fault_tolerance import elastic_restore
 
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+mesh8 = make_test_mesh((4, 2), ("data", "model"))
+mesh4 = make_test_mesh((2, 2), ("data", "model"))
 spec = {{"w": P("model", None)}}
 w = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 tree = {{"w": jax.device_put(w, NamedSharding(mesh8, spec["w"]))}}
@@ -144,16 +148,16 @@ print("elastic restore ok")
 def test_compressed_psum_int8():
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_test_mesh
 from repro.optim.compression import compressed_psum_int8
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_test_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-out = shard_map(lambda v: compressed_psum_int8(v[0], "data")[None],
-                mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                check_rep=False)(x)
+out = jax.shard_map(lambda v: compressed_psum_int8(v[0], "data")[None],
+                    mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                    check_vma=False)(x)
 want = x.sum(0)
 got = np.asarray(out[0])
 scale = float(jnp.max(jnp.abs(x))) / 127
@@ -167,6 +171,7 @@ def test_mini_dryrun_lower_compile():
     with memory/cost/collective extraction end to end."""
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_test_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import get_arch
 from repro.models.families import build_model
@@ -180,7 +185,7 @@ from repro.launch import hlo_analysis
 cfg = get_arch("olmoe_1b_7b").reduced()
 model = build_model(cfg)
 pshapes = jax.eval_shape(model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 ctx = shctx.make_context(mesh, num_kv_heads=cfg.num_kv_heads)
 pspecs = ShardingPlan().param_specs(pshapes)
 pshard = shardings_for(mesh, pspecs)

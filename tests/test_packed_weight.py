@@ -142,7 +142,7 @@ def test_pack_tree_emits_packed_weights_including_stacked():
     assert isinstance(pw, PackedWeight)
     assert pw.dense_shape == (8, 32)           # per-layer shape
     assert pw.stack_dims == (3,)
-    assert pw.values.shape == (3, 8, 2, 2)     # (L, O, G, Ne)
+    assert pw.values.shape == (3, 2, 2, 8)     # (L, G, Ne, O)
     # dense weights untouched
     np.testing.assert_array_equal(np.asarray(packed["norm"]["scale"]),
                                   np.asarray(tree["norm"]["scale"]))
@@ -167,9 +167,9 @@ def test_param_specs_structural_for_packed_weights():
                       "attn": {"wq": lin(2)}})
     specs = ShardingPlan().param_specs(tree)
     assert isinstance(specs["mlp"]["gate"], PackedWeight)
-    assert specs["mlp"]["gate"].values == P("model", None, None)    # col
-    assert specs["mlp"]["down"].values == P(None, "model", None)    # row
-    assert specs["attn"]["wq"].values == P("model", None, None)     # col
+    assert specs["mlp"]["gate"].values == P(None, None, "model")    # col
+    assert specs["mlp"]["down"].values == P("model", None, None)    # row
+    assert specs["attn"]["wq"].values == P(None, None, "model")     # col
     # kv-replication classifies structurally too
     tree2 = pack_tree({"attn": {"wk": lin(3)}})
     specs2 = ShardingPlan(attn_kv_replicated=True).param_specs(tree2)
@@ -295,7 +295,7 @@ def test_pack_block_geometry_and_pytree():
     assert pw.layout == "block"
     br, a_max = pw.block_geom
     assert br == 8
-    assert pw.values.shape == (4, a_max, 8, CFG.n_effective)
+    assert pw.values.shape == (4, a_max, CFG.n_effective, 8)
     assert pw.indices.shape == pw.values.shape
     assert pw.active_groups.shape == (4, a_max)
     # three traced children; aux (incl. geometry) survives a flatten cycle
@@ -325,7 +325,7 @@ def test_block_apply_parity_vs_ref_oracle():
     want_oracle = np.asarray(block_spmm_ref(
         pw.active_groups, pw.values, pw.indices, x.T, CFG, 32).T)
     want_dense = np.asarray(x @ w.T)
-    for backend in ("reference", "block_spmm"):
+    for backend in ("reference", "block_spmm_interpret"):
         y = sl.apply(pw, x, ExecPolicy(mode="packed", backend=backend))
         np.testing.assert_allclose(np.asarray(y), want_oracle,
                                    rtol=1e-5, atol=1e-5)
@@ -400,7 +400,7 @@ def test_pack_tree_block_stacked_scan_slices():
     assert pw.layout == "block" and pw.stack_dims == (3,)
     assert pw.dense_shape == (8, 32)
     br, a_max = pw.block_geom
-    assert pw.values.shape == (3, 8 // br, a_max, br, cfg.n_effective)
+    assert pw.values.shape == (3, 8 // br, a_max, cfg.n_effective, br)
     # slicing the layer axis (what lax.scan does) == packing that slice with
     # the shared a_max
     sliced = jax.tree.map(lambda a: a[1], pw)
@@ -445,7 +445,7 @@ def test_pack_block_a_max_validation_and_padding():
     # matching an existing checkpoint's geometry) — still lossless
     pw = pack_block(w, CFG, block_r=8, a_max=5)
     assert pw.block_geom == (8, 5)
-    assert pw.values.shape == (1, 5, 8, CFG.n_effective)
+    assert pw.values.shape == (1, 5, CFG.n_effective, 8)
     np.testing.assert_array_equal(np.asarray(pw.to_dense()), np.asarray(w))
     # an undersized explicit a_max raises — including on the stacked path,
     # whose per-slice packers run under vmap and cannot check it themselves
@@ -459,8 +459,9 @@ def test_pack_block_a_max_validation_and_padding():
 
 def test_block_auto_dispatch_resolves_block_spmm(tmp_path):
     """backend='auto' can resolve a block-layout weight to the block_spmm
-    kernel on CPU: forced cache entries dispatch it (numerics unchanged) and
-    the autotuner measures it as a first-class, dispatchable candidate."""
+    kernel (its interpret-mode twin on CPU): forced cache entries dispatch
+    it (numerics unchanged) and the autotuner measures it as a first-class,
+    dispatchable candidate."""
     from repro import tune
 
     cache = tune.TuneCache(path=str(tmp_path / "cache.json"))
@@ -471,7 +472,7 @@ def test_block_auto_dispatch_resolves_block_spmm(tmp_path):
         p = tune.Problem.for_xwT_block(x.shape, pw, x.dtype)
         assert f"b{pw.block_geom[0]}x{pw.block_geom[1]}" in \
             tune.problem_key(p)
-        cache.put(p, tune.TunedConfig(backend="block_spmm",
+        cache.put(p, tune.TunedConfig(backend="block_spmm_interpret",
                                       params={"cd_block": 8}))
         y = jax.jit(lambda pw_, x_: sl.apply(
             pw_, x_, ExecPolicy(mode="packed", backend="auto")))(pw, x)
@@ -484,7 +485,7 @@ def test_block_auto_dispatch_resolves_block_spmm(tmp_path):
                                       max_measure=2, warmup=1, iters=1)
         measured = {c.backend for c in res.candidates
                     if c.status == "measured"}
-        assert "block_spmm" in measured   # dispatchable, not measure-only
+        assert "block_spmm_interpret" in measured   # not measure-only
         assert res.best.backend in measured
     finally:
         tune.set_default_cache(None)

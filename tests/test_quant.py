@@ -63,7 +63,7 @@ def test_quantized_pytree_children_and_aux():
     # block layout: 4 children, scales per (row-block, group, row)
     _, bpw = _block_pw()
     bq = quantize_packed(bpw)
-    assert bq.scales.shape == bq.values.shape[:-1]
+    assert bq.scales.shape == bq.values.shape[:-2] + bq.values.shape[-1:]
     leaves_b, treedef_b = jax.tree_util.tree_flatten(bq)
     assert len(leaves_b) == 4    # + active_groups
     assert jax.tree_util.tree_unflatten(treedef_b, leaves_b).qdtype == "int8"
@@ -80,7 +80,7 @@ def test_quantized_constructor_validation():
     with pytest.raises(ValueError, match="unknown qdtype"):
         quantize_packed(pw, "int4")
     with pytest.raises(ValueError, match="scales shape"):
-        PackedWeight(jnp.zeros((16, 4, 2), jnp.int8), pw.indices, cfg=CFG,
+        PackedWeight(jnp.zeros((4, 2, 16), jnp.int8), pw.indices, cfg=CFG,
                      dense_shape=(16, 64), scales=jnp.ones((4,)),
                      qdtype="int8")
     q = quantize_packed(pw)
@@ -94,7 +94,7 @@ def test_quantization_error_bound_and_dequantize():
     _, pw = _pw(o=32, k=128)
     q = quantize_packed(pw)
     err = jnp.abs(q.dequantized_values() - pw.values)
-    bound = 0.5 * q.scales[:, None, None] * (1 + 1e-6)
+    bound = 0.5 * q.scales[None, None, :] * (1 + 1e-6)
     assert bool(jnp.all(err <= bound))
     d = dequantize_packed(q)
     assert d.qdtype is None and d.scales is None
@@ -102,7 +102,7 @@ def test_quantization_error_bound_and_dequantize():
     # amax calibration really uses the per-row max
     np.testing.assert_allclose(
         np.asarray(amax_scales(pw)),
-        np.asarray(jnp.max(jnp.abs(pw.values), axis=(1, 2)) / 127.0),
+        np.asarray(jnp.max(jnp.abs(pw.values), axis=(0, 1)) / 127.0),
         rtol=1e-6)
 
 
@@ -136,12 +136,12 @@ def test_block_q8_parity_all_backends(batch):
     y_f = np.asarray(sl.apply(bpw, x, ExecPolicy(mode="packed")))
     tol = _parity_tol(q, x)
     ys = {}
-    for backend in ("reference", "block_spmm", "auto"):
+    for backend in ("reference", "block_spmm_interpret", "auto"):
         y = np.asarray(sl.apply(
             q, x, ExecPolicy(mode="packed", backend=backend)))
         assert np.max(np.abs(y - y_f)) <= tol, backend
         ys[backend] = y
-    np.testing.assert_allclose(ys["reference"], ys["block_spmm"],
+    np.testing.assert_allclose(ys["reference"], ys["block_spmm_interpret"],
                                rtol=1e-4, atol=1e-5)
 
 
@@ -246,9 +246,9 @@ def test_param_specs_shard_scales_alongside_values():
     tree = pack_tree({"mlp": {"gate": lin(0), "down": lin(1)}},
                      quantize="int8")
     specs = ShardingPlan().param_specs(tree)
-    assert specs["mlp"]["gate"].values == P("model", None, None)   # col
+    assert specs["mlp"]["gate"].values == P(None, None, "model")   # col
     assert specs["mlp"]["gate"].scales == P("model")
-    assert specs["mlp"]["down"].values == P(None, "model", None)   # row
+    assert specs["mlp"]["down"].values == P("model", None, None)   # row
     assert specs["mlp"]["down"].scales == P(None)                  # no G axis
     btree = pack_tree({"mlp": {"gate": lin(0), "down": lin(1)}},
                       layout="block", quantize="int8")
@@ -256,28 +256,28 @@ def test_param_specs_shard_scales_alongside_values():
     assert bspecs["mlp"]["gate"].values == P("model", None, None, None)
     assert bspecs["mlp"]["gate"].scales == P("model", None, None)
     assert bspecs["mlp"]["down"].scales == P(None, None, None)
-    # per-group xwT scales (O, G) shard the group axis under row-parallel —
+    # per-group xwT scales (G, O) shard the group axis under row-parallel —
     # it tiles the contraction dim exactly like the values' group axis
     gtree = pack_tree({"mlp": {"gate": lin(0), "down": lin(1)}},
                       quantize="int8", granularity="per_group")
     gspecs = ShardingPlan().param_specs(gtree)
-    assert gspecs["mlp"]["gate"].scales == P("model", None)
-    assert gspecs["mlp"]["down"].scales == P(None, "model")
+    assert gspecs["mlp"]["gate"].scales == P(None, "model")
+    assert gspecs["mlp"]["down"].scales == P("model", None)
 
 
 @pytest.mark.parametrize("batch", [5, 8])
 def test_xwT_q8_per_group_scales(batch):
-    """Per-group xwT granularity: scales (O, G), tighter error than
+    """Per-group xwT granularity: scales (G, O), tighter error than
     per-row, full backend parity (reference / Pallas / auto)."""
     params, pw = _pw(o=16, k=64)
     q = quantize_packed(pw, granularity="per_group")
-    assert q.scales.shape == (16, 4)
+    assert q.scales.shape == (4, 16)
     # per-group error bound: every value errs <= its group scale / 2
     err = jnp.abs(q.dequantized_values() - pw.values)
-    assert bool(jnp.all(err <= 0.5 * q.scales[..., None] * (1 + 1e-6)))
+    assert bool(jnp.all(err <= 0.5 * q.scales[:, None, :] * (1 + 1e-6)))
     # per-group grids are never coarser than the row grid
     qr = quantize_packed(pw)
-    assert bool(jnp.all(q.scales <= qr.scales[:, None] * (1 + 1e-6)))
+    assert bool(jnp.all(q.scales <= qr.scales[None, :] * (1 + 1e-6)))
     x = jax.random.normal(jax.random.PRNGKey(1), (batch, 64))
     ys = {}
     for backend in ("reference", "pallas_interpret", "auto"):

@@ -98,7 +98,7 @@ def test_reconfigure_k_views():
     a = random_sparse_dense(rng, 8, 128, cfg)
     p = pack(jnp.asarray(a), cfg)
     split = reconfigure_k(p, k=4)  # view as 4 passes of 2:64
-    assert split.values.shape == (8, 2 * 4, 2)
+    assert split.values.shape == (2 * 4, 2, 8)
     assert split.cfg.n == 2 and split.cfg.k == 4
     # the multiset of (value) entries is preserved
     np.testing.assert_allclose(

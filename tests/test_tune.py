@@ -221,6 +221,38 @@ def test_autotune_and_auto_backend_match_reference(tmp_path):
         tune.set_default_cache(None)
 
 
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_autotune_kernel_failing_at_default_tiles(platform):
+    """On a TPU a Pallas kernel that fails at its own default tiles is a
+    broken kernel: autotune raises instead of recording it and quietly
+    serving the reference.  Elsewhere (and at non-default tiles) a failing
+    candidate is recorded and skipped."""
+    from repro.tune.autotune import _autotune
+
+    p = tune.Problem(op="xwT", rows=8, out=256, k=256, dtype="bfloat16",
+                     sparsity=(8, 128, 1), platform=platform)
+
+    def make_thunk(c):
+        def thunk():
+            if c.backend != "reference":
+                raise RuntimeError(f"{c.backend} refused by the compiler")
+            return jnp.zeros(())
+        return thunk
+
+    def run():
+        return _autotune(p, make_thunk, vmem_budget=2 ** 30, max_measure=4,
+                         warmup=0, iters=1, cache=None, persist=False)
+
+    if platform == "tpu":
+        with pytest.raises(RuntimeError, match="pallas refused"):
+            run()
+    else:
+        res = run()
+        assert res.best.backend == "reference"
+        assert all(c.status == "error" for c in res.candidates
+                   if c.backend != "reference" and c.status != "pruned_rank")
+
+
 def test_auto_backend_spmm_matches_reference():
     rng = np.random.default_rng(4)
     a = random_sparse_dense(rng, 16, 32, SP)
