@@ -9,9 +9,11 @@ The observability layer every perf claim in this repo is judged against
   with monotonic timestamps), attached to each registry as ``.trace``.
 * :mod:`repro.obs.log`     — level-filtered structured logger (text or JSON
   lines) used by the ``launch/`` drivers.
-* :mod:`repro.obs.profile` — opt-in kernel profiling: ``annotate(name)``
-  names DeMM kernels in profiler traces, ``profile(trace_dir)`` dumps a
-  jax profiler trace directory for TensorBoard/perfetto.
+* :mod:`repro.obs.profile` — profiling: ``annotate(name)`` names DeMM
+  kernels in profiler traces, ``phase(name, counter)`` puts a span of host
+  work on the profiler's clock and its seconds in a counter,
+  ``profile(trace_dir)`` dumps a jax profiler trace directory for
+  TensorBoard/perfetto.
 
 Observability v2 (DESIGN.md §16) adds:
 
@@ -51,7 +53,7 @@ from repro.obs.metrics import (
     run_metadata,
     set_default_registry,
 )
-from repro.obs.profile import annotate, profile, profiling_active
+from repro.obs.profile import annotate, phase, profile
 from repro.obs.recorder import FlightRecorder, Watchdog
 from repro.obs.sketch import QuantileSketch
 from repro.obs.slo import SLOConfig, phase_sketches, request_phases, slo_report
@@ -62,8 +64,8 @@ __all__ = [
     "Gauge", "Histogram", "LEVELS", "MetricsRegistry", "QuantileSketch",
     "SLOConfig", "Span", "StructuredLogger", "TraceContext", "Watchdog",
     "annotate", "current_context", "default_registry", "event",
-    "get_logger", "metrics", "new_trace_id", "phase_sketches", "profile",
-    "profiling_active", "request_phases", "run_metadata",
+    "get_logger", "metrics", "new_trace_id", "phase", "phase_sketches",
+    "profile", "request_phases", "run_metadata",
     "set_default_registry", "slo_report", "use_context",
 ]
 

@@ -33,10 +33,21 @@ host and pushed to the device pytree before each program call — value-only
 updates, never a retrace.  Layering: this module never imports
 ``repro.models``; the model (and its two compiled entry points) is injected
 by the caller.
+
+Every tick runs as a numbered ``serve.tick`` step span, and each of its
+phases under an ``obs.phase`` span (``serve.admit``, ``serve.control``,
+``serve.prefill.dispatch``/``.wait``, ``serve.decode.dispatch``/``.wait``,
+``serve.sample``, ``serve.pages``): an attached profiler records them on the
+device trace's clock, and their host seconds add up in
+``serve_phase_seconds_total{phase=<name without serve., dots as _>}``.  The
+two compiled programs are named ``decode_step`` and ``prefill_chunk``
+(``jit_decode_step``/``jit_prefill_chunk`` in a trace), and
+``serve_compiles_total{program}`` counts each new executable they build.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import List, Optional
@@ -51,6 +62,10 @@ from repro.paged.prefill import ChunkedPrefill
 from repro.paged.scheduler import SchedConfig, Scheduler, Stage
 from repro.serve.protocol import EngineBase
 from repro.serve.serve_loop import Request
+
+# tick phases, each the ``serve.<name>`` span and the counter label
+PHASES = ("tick", "admit", "control", "prefill.dispatch", "prefill.wait",
+          "decode.dispatch", "decode.wait", "sample", "pages")
 
 
 @dataclasses.dataclass
@@ -105,11 +120,16 @@ class PagedServeEngine(EngineBase):
         self.state = self._place_state(model.init_decode_state(
             cfg.num_slots, cfg.max_len, dtype=jnp.float32,
             paged=self.layout))
-        self._decode = self._wrap_step(jax.jit(
-            lambda p, s, t: model.decode_step(p, s, t, policy=policy)))
+
+        def decode_step(p, s, t):
+            return model.decode_step(p, s, t, policy=policy)
+
+        decode_jit = jax.jit(decode_step)
+        self._decode = self._wrap_step(decode_jit)
         self.prefill = ChunkedPrefill(model, chunk=cfg.prefill_chunk,
                                       policy=policy)
         self._prefill_step = self._wrap_step(self.prefill.step)
+        self._jits = {"decode": decode_jit, "prefill": self.prefill._fn}
         self.sched = Scheduler(cfg.sched)
         # host mirrors of the control leaves (pushed before each program)
         self._pos = np.zeros((cfg.num_slots,), np.int32)
@@ -154,8 +174,15 @@ class PagedServeEngine(EngineBase):
         self._m_tok_lat = m.histogram(
             "serve_decode_token_seconds",
             help="decode-step latency per generated token")
-        self._m_tick = m.histogram(
-            "serve_tick_seconds", help="full engine tick duration")
+        self._m_phase = {p: m.counter(
+            "serve_phase_seconds_total",
+            help="host seconds in each engine tick phase",
+            phase=p.replace(".", "_")) for p in PHASES}
+        self._m_ticks = m.counter("serve_ticks_total", help="engine ticks")
+        self._m_compiles = {p: m.counter(
+            "serve_compiles_total",
+            help="executables a dispatch of the program traced and compiled",
+            program=p) for p in self._jits}
         self._m_slots = m.gauge(
             "serve_slots_active", help="occupied decode slots")
         self._m_queue_depth = m.gauge(
@@ -250,14 +277,32 @@ class PagedServeEngine(EngineBase):
         Static kind/layout leaves never change, so no retrace.  The mirrors
         are COPIED before upload — jax's CPU client may zero-copy-alias an
         aligned numpy buffer, and these arrays keep mutating in place."""
-        c = self.state["caches"]
-        self.state = {
-            **self.state,
-            "pos": jnp.asarray(np.array(self._pos)),
-            "caches": {**c,
-                       "block_table": jnp.asarray(np.array(self.kv.table)),
-                       "active": jnp.asarray(np.array(self._decode_mask))},
-        }
+        with self._phase("control"):
+            c = self.state["caches"]
+            self.state = {
+                **self.state,
+                "pos": jnp.asarray(np.array(self._pos)),
+                "caches": {**c,
+                           "block_table": jnp.asarray(np.array(self.kv.table)),
+                           "active": jnp.asarray(np.array(self._decode_mask))},
+            }
+
+    def _phase(self, name: str, **meta):
+        """``obs.phase`` span ``serve.<name>`` into its phase counter."""
+        return obs.phase("serve." + name, self._m_phase[name], **meta)
+
+    @contextlib.contextmanager
+    def _dispatch(self, program: str, **meta):
+        """The ``serve.<program>.dispatch`` span around one call of a
+        compiled program; a call that grew the program's jit cache traced
+        and compiled a new executable."""
+        fn = self._jits[program]
+        before = fn._cache_size()
+        with self._phase(program + ".dispatch", **meta):
+            yield
+        grown = fn._cache_size() - before
+        if grown > 0:
+            self._m_compiles[program].inc(grown)
 
     def _page_gauges(self):
         self._m_pages_free.set(self.kv.pages_free)
@@ -370,14 +415,14 @@ class PagedServeEngine(EngineBase):
             self._m_queue_depth.set(len(self.sched))
             self._page_gauges()
 
-    def _finish_prefill(self, slot: int, req: Request, logits, now: float):
-        """Final chunk done: sample the next token from its logits (first
-        generated token for a fresh request; the continuation token for a
-        preempt-resume).  The sampler key is the token's absolute sequence
-        index (= the work length), so a resume re-draws the identical
-        token the uninterrupted run committed there."""
-        tok = self.sampler.sample(np.asarray(logits[0, 0], np.float32),
-                                  req.uid, int(self._pos[slot]))
+    def _finish_prefill(self, slot: int, req: Request, logits: np.ndarray,
+                        now: float):
+        """Final chunk done: sample the next token from its host logits row
+        (first generated token for a fresh request; the continuation token
+        for a preempt-resume).  The sampler key is the token's absolute
+        sequence index (= the work length), so a resume re-draws the
+        identical token the uninterrupted run committed there."""
+        tok = self.sampler.sample(logits, req.uid, int(self._pos[slot]))
         req.output.append(tok)
         self._next_tok[slot, 0] = tok
         self._m_tokens.inc()
@@ -424,8 +469,9 @@ class PagedServeEngine(EngineBase):
                 # prefill_chunk event (and any compile-time kernel_dispatch
                 # events) carry its trace_id
                 with obs.use_context(self._request_context(req)):
-                    logits, self.state, fed = self._prefill_step(
-                        self.params, self.state, self._work[i], was, i)
+                    with self._dispatch("prefill", uid=req.uid):
+                        logits, self.state, fed = self._prefill_step(
+                            self.params, self.state, self._work[i], was, i)
                     self.trace.event("prefill_chunk", uid=req.uid, slot=i,
                                      fed_from=was, fed_to=fed)
                 self._fed[i] = fed
@@ -435,8 +481,12 @@ class PagedServeEngine(EngineBase):
                 self._m_prefill_tok.inc(fed - was)
                 budget -= 1
                 if fed == len(self._work[i]):
-                    self._finish_prefill(i, req, logits, time.monotonic())
-            self._page_gauges()
+                    with self._phase("prefill.wait", uid=req.uid):
+                        row = np.asarray(logits[0, 0], np.float32)
+                    with self._phase("sample"):
+                        self._finish_prefill(i, req, row, time.monotonic())
+            with self._phase("pages"):
+                self._page_gauges()
 
     def _grow_or_preempt(self, tokens_for):
         """Grow every decoding slot's pages to hold ``tokens_for(i)``
@@ -467,7 +517,8 @@ class PagedServeEngine(EngineBase):
         return self._run_decode_plain()
 
     def _run_decode_plain(self) -> int:
-        self._grow_or_preempt(lambda i: int(self._pos[i]) + 1)
+        with self._phase("pages"):
+            self._grow_or_preempt(lambda i: int(self._pos[i]) + 1)
         if not self._decode_mask.any():
             return 0
         self._sync_control()
@@ -475,33 +526,38 @@ class PagedServeEngine(EngineBase):
         first = next(i for i in range(self.cfg.num_slots)
                      if self._decode_mask[i])
         # batched dispatch: attributed to the first decode-ready lane
-        with obs.use_context(self._request_context(self.active[first])):
+        with obs.use_context(self._request_context(self.active[first])), \
+                self._dispatch("decode"):
             logits, self.state = self._decode(
                 self.params, self.state,
                 jnp.asarray(np.array(self._next_tok)))
-        logits = np.asarray(logits[:, 0], np.float32)   # device sync
+        with self._phase("decode.wait"):
+            logits = np.asarray(logits[:, 0], np.float32)   # device sync
         step_dt = time.perf_counter() - t0
         self._m_disp_decode.inc()
         now = time.monotonic()
         n = 0
-        for i in range(self.cfg.num_slots):
-            if not self._decode_mask[i]:
-                continue
-            n += 1
-            req = self.active[i]
-            self._pos[i] += 1
-            self.kv.note_tokens(i, int(self._pos[i]))
-            tok = self.sampler.sample(logits[i], req.uid, int(self._pos[i]))
-            req.output.append(tok)
-            self._next_tok[i, 0] = tok
-            self._m_tokens.inc()
-            self._m_tok_lat.observe(step_dt)
-            self._sk_tok.observe(step_dt)
-            if (len(req.output) >= req.max_new_tokens or
-                    (req.eos_id is not None and tok == req.eos_id) or
-                    int(self._pos[i]) >= self.cfg.max_len - 1):
-                self._complete(i, req, now)
-        self._page_gauges()
+        with self._phase("sample"):
+            for i in range(self.cfg.num_slots):
+                if not self._decode_mask[i]:
+                    continue
+                n += 1
+                req = self.active[i]
+                self._pos[i] += 1
+                self.kv.note_tokens(i, int(self._pos[i]))
+                tok = self.sampler.sample(logits[i], req.uid,
+                                          int(self._pos[i]))
+                req.output.append(tok)
+                self._next_tok[i, 0] = tok
+                self._m_tokens.inc()
+                self._m_tok_lat.observe(step_dt)
+                self._sk_tok.observe(step_dt)
+                if (len(req.output) >= req.max_new_tokens or
+                        (req.eos_id is not None and tok == req.eos_id) or
+                        int(self._pos[i]) >= self.cfg.max_len - 1):
+                    self._complete(i, req, now)
+        with self._phase("pages"):
+            self._page_gauges()
         return n
 
     def _run_decode_spec(self, g_eff: int) -> int:
@@ -512,7 +568,8 @@ class PagedServeEngine(EngineBase):
         pages beyond the committed tokens (same tick — rejected drafts
         never hold arena capacity across ticks)."""
         # positions pos .. pos+g_eff are written -> pos+g_eff+1 tokens
-        self._grow_or_preempt(lambda i: int(self._pos[i]) + g_eff + 1)
+        with self._phase("pages"):
+            self._grow_or_preempt(lambda i: int(self._pos[i]) + g_eff + 1)
         lanes = [i for i in range(self.cfg.num_slots) if self._decode_mask[i]]
         if not lanes:
             return 0
@@ -525,23 +582,39 @@ class PagedServeEngine(EngineBase):
         d_state = self.state                # self.state stays pre-draft
         window_ctx = self._request_context(self.active[lanes[0]])
         for j in range(g_eff):
-            with obs.use_context(window_ctx):
+            with obs.use_context(window_ctx), self._dispatch("decode"):
                 d_logits, d_state = self._decode(
                     self._draft_params, d_state,
                     jnp.asarray(window[:, j:j + 1]))
-            d_logits = np.asarray(d_logits[:, 0], np.float32)
+            with self._phase("decode.wait"):
+                d_logits = np.asarray(d_logits[:, 0], np.float32)
             self._m_disp_draft.inc()
-            for i in lanes:
-                window[i, j + 1] = self.sampler.sample(
-                    d_logits[i], self.active[i].uid, int(pos0[i]) + j + 1)
-        with obs.use_context(window_ctx):
+            with self._phase("sample"):
+                for i in lanes:
+                    window[i, j + 1] = self.sampler.sample(
+                        d_logits[i], self.active[i].uid,
+                        int(pos0[i]) + j + 1)
+        with obs.use_context(window_ctx), self._phase("decode.dispatch"):
             f_logits, new_state = self._verify(self.params, self.state,
                                                jnp.asarray(window))
-        f_logits = np.asarray(f_logits, np.float32)
+        with self._phase("decode.wait"):
+            f_logits = np.asarray(f_logits, np.float32)
         self._m_disp_verify.inc()
         self.state = new_state
         window_dt = time.perf_counter() - t0
         now = time.monotonic()
+        with self._phase("sample"):
+            self._commit_window(lanes, pos0, window, f_logits, g_eff,
+                                window_dt, now)
+        with self._phase("pages"):
+            self._page_gauges()
+        return len(lanes)
+
+    def _commit_window(self, lanes, pos0, window, f_logits, g_eff: int,
+                       window_dt: float, now: float):
+        """Commit each lane's accepted prefix + correcting/bonus token from
+        the verify logits, trim the pages past it, and record the window."""
+        W = g_eff + 1
         drafted = accepted = committed = 0
         for i in lanes:
             req = self.active[i]
@@ -596,24 +669,24 @@ class PagedServeEngine(EngineBase):
                 self._m_tok_lat.observe(per_tok)
                 self._sk_tok.observe(per_tok)
         self._spec_metrics.observe_window(drafted, accepted, committed)
-        self._page_gauges()
-        return len(lanes)
 
     # -- public loop --------------------------------------------------------
 
     def step(self) -> int:
         """One engine tick (admit → prefill → decode).  Returns the number
         of occupied slots after the tick."""
-        t_tick = time.perf_counter()
-        self._beat()
         self.tick_count += 1
-        self._admit()
-        self._run_prefill()
-        self._run_decode()
-        n_active = sum(r is not None for r in self.active)
-        self._m_slots.set(n_active)
-        self._m_queue_depth.set(len(self.sched))
-        self._m_tick.observe(time.perf_counter() - t_tick)
+        with obs.phase("serve.tick", self._m_phase["tick"],
+                       step_num=self.tick_count):
+            self._beat()
+            with self._phase("admit"):
+                self._admit()
+            self._run_prefill()
+            self._run_decode()
+            n_active = sum(r is not None for r in self.active)
+            self._m_slots.set(n_active)
+            self._m_queue_depth.set(len(self.sched))
+        self._m_ticks.inc()
         return n_active
 
     def run_until_drained(self, max_ticks: int = 10000):

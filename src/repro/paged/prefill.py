@@ -36,9 +36,11 @@ class ChunkedPrefill:
                 f"{type(model).__name__} has no prefill_chunk (chunked "
                 "paged prefill needs an attention-cache family)")
         self.chunk = int(chunk)
-        self._fn = jax.jit(
-            lambda p, s, t, slot, n: model.prefill_chunk(
-                p, s, t, slot, n, policy=policy))
+
+        def prefill_chunk(p, s, t, slot, n):
+            return model.prefill_chunk(p, s, t, slot, n, policy=policy)
+
+        self._fn = jax.jit(prefill_chunk)
         self.dispatches = 0           # compiled-program invocations issued
 
     def num_chunks(self, prompt_len: int) -> int:

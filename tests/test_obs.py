@@ -416,3 +416,20 @@ def test_validator_histogram_consistency_check():
                             "counts": [1, 0], "sum": 0.5, "count": 2}]}
     errs = vm.check_histogram(snap, "h")
     assert any("sum(counts)" in e for e in errs)
+
+
+def test_phase_adds_its_seconds_to_the_counter():
+    c = MetricsRegistry().counter("phase_seconds_total")
+    with obs.phase("serve.test", c, uid=3):
+        pass
+    first = c.value
+    assert first > 0
+    with obs.phase("serve.test", c, step_num=7):
+        pass
+    assert c.value > first
+    with obs.phase("serve.test"):           # no counter: a span alone
+        pass
+    with pytest.raises(KeyError):
+        with obs.phase("serve.test", c):
+            raise KeyError("propagates")
+    assert c.value > first

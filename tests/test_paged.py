@@ -373,3 +373,89 @@ def test_encdec_paged_prefill_matches_decode_steps():
     np.testing.assert_allclose(np.asarray(logits_pf[0, 0], np.float32),
                                np.asarray(logits_st[0, 0], np.float32),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# tick phases, named programs and compile counts
+# ---------------------------------------------------------------------------
+
+def _counters(reg, name, label):
+    return {c["labels"][label]: c["value"]
+            for c in reg.snapshot(meta=False)["counters"]
+            if c["name"] == name}
+
+
+def test_tick_phases_ticks_and_compiles_are_counted(paged_setup):
+    """Every tick phase adds its host seconds to its own counter, none more
+    than the whole tick; ticks are counted; each program compiles once in
+    warm-up and never again for the same shapes."""
+    cfg, model, params = paged_setup
+    reg = MetricsRegistry()
+    eng = PagedServeEngine(
+        model, params,
+        PagedServeConfig(num_slots=4, max_len=96, page_size=8,
+                         prefill_chunk=16),
+        metrics=reg)
+    _serve(eng, _prompts(cfg, (5, 23)))
+    warm_ticks = eng.tick_count
+    assert _counters(reg, "serve_compiles_total", "program") == {
+        "decode": 1, "prefill": 1}
+    for i, p in enumerate(_prompts(cfg, (30, 9, 17), seed=1)):
+        eng.submit(Request(uid=10 + i, prompt=p, max_new_tokens=5))
+    for _ in range(6):
+        eng.step()
+    assert _counters(reg, "serve_compiles_total", "program") == {
+        "decode": 1, "prefill": 1}
+    assert reg.counter("serve_ticks_total").value == warm_ticks + 6
+    phases = _counters(reg, "serve_phase_seconds_total", "phase")
+    assert set(phases) == {"tick", "admit", "control", "prefill_dispatch",
+                           "prefill_wait", "decode_dispatch", "decode_wait",
+                           "sample", "pages"}
+    assert all(0 < v <= phases["tick"] for v in phases.values())
+
+
+def test_compiled_programs_are_named(paged_setup):
+    cfg, model, params = paged_setup
+    eng = PagedServeEngine(
+        model, params,
+        PagedServeConfig(num_slots=2, max_len=32, page_size=8,
+                         prefill_chunk=8),
+        metrics=MetricsRegistry())
+    decode = eng._jits["decode"].lower(
+        eng.params, eng.state, jnp.zeros((2, 1), jnp.int32)).as_text()
+    prefill = eng._jits["prefill"].lower(
+        eng.params, eng.state, jnp.zeros((8,), jnp.int32), jnp.int32(0),
+        jnp.int32(8)).as_text()
+    assert "module @jit_decode_step" in decode
+    assert "module @jit_prefill_chunk" in prefill
+
+
+def test_profiler_trace_holds_the_tick_phases(paged_setup, tmp_path):
+    """The phases are profiler spans: a CPU trace of a few ticks holds each
+    ``serve.*`` span in its host plane, one ``serve.tick`` per tick."""
+    cfg, model, params = paged_setup
+    eng = PagedServeEngine(
+        model, params,
+        PagedServeConfig(num_slots=2, max_len=64, page_size=8,
+                         prefill_chunk=16),
+        metrics=MetricsRegistry())
+    _serve(eng, _prompts(cfg, (20,)))           # compile outside the trace
+    eng.submit(Request(uid=5, prompt=_prompts(cfg, (20,))[0],
+                       max_new_tokens=3))
+    jax.profiler.start_trace(str(tmp_path))
+    for _ in range(3):
+        eng.step()
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    seen = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("serve."):
+                        name = e.name.split("#", 1)[0]
+                        seen[name] = seen.get(name, 0) + 1
+    assert seen["serve.tick"] == 3
+    assert {"serve.admit", "serve.control", "serve.prefill.dispatch",
+            "serve.prefill.wait", "serve.decode.dispatch",
+            "serve.decode.wait", "serve.sample", "serve.pages"} <= set(seen)
