@@ -25,14 +25,21 @@ of x: the smallest chunk whose x block spans a multiple of 128 lanes, so
 fine patterns (2:4, 8:16) feed the MXU a full-lane contraction.  The output
 block is revisited across chunks and accumulated in fp32.
 
-VMEM budget (defaults, bf16 x, 8:128, block_o = 128): x block Bt×128×2,
-values + indices 8×128×(2+4) = 6 KiB, Sᵀ 128×128×4 = 64 KiB, out block
-Bt×128×4 — far inside the scoped VMEM limit with double buffering.
+Sᵀ is built one sublane-aligned slice at a time (:func:`slice_rows`):
+``lcm(M, 8)`` rows covering whole groups, each from only the groups it
+covers, so a step of ``chunk`` groups runs N select passes per group, not
+``chunk·N`` over all ``chunk·M`` rows.
+
+VMEM budget (block_o = 128, bf16 x, f32 values): x block Bt×chunk·M×2,
+values + indices chunk×Ne×128×(4+4), Sᵀ chunk·M×128×4 (64 KiB at 8:128,
+chunk 1; 320 KiB at 5:80, chunk 8), out block Bt×128×4 — far inside the
+scoped VMEM limit with double buffering.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +79,30 @@ def group_chunk(groups: int, m: int) -> int:
     return groups
 
 
+def slice_rows(chunk_rows: int, m: int) -> int:
+    """Rows of Sᵀ built at a time: ``lcm(M, 8)``, the fewest whole groups
+    that fill whole f32 sublane tiles, or all ``chunk_rows`` where that
+    does not divide them (a step shorter than one such slice)."""
+    h = math.lcm(m, SUBLANES)
+    return h if chunk_rows % h == 0 else chunk_rows
+
+
+def count_scatter_slices(op: str, chunk: int, m: int):
+    """Trace-time audit of the Sᵀ expansion of one packed ``pallas_call``
+    of ``op`` whose grid steps hold ``chunk`` groups:
+    ``kernel_scatter_slices_total{op, slice_rows, chunk_rows}`` on the
+    default registry, beside ``kernel_dispatch_total``.  ``slice_rows <
+    chunk_rows`` marks a matmul built in several slices."""
+    from repro import obs
+
+    rows = chunk * m
+    obs.metrics().counter(
+        "kernel_scatter_slices_total",
+        help="packed kernel calls per (op, Sᵀ rows built at a time, Sᵀ "
+             "rows per grid step)",
+        op=op, slice_rows=slice_rows(rows, m), chunk_rows=rows).inc()
+
+
 def _scatter_matrix(values_ref, indices_ref, m: int, scales=None):
     """Expand a packed ``(chunk, N, cols)`` block into the fp32 scatter
     matrix Sᵀ ``(chunk * M, cols)`` — the in-VMEM image of DeMM's N read
@@ -79,8 +110,10 @@ def _scatter_matrix(values_ref, indices_ref, m: int, scales=None):
 
         Sᵀ[g*M + j, c] = sum_n values[g, n, c] * [indices[g, n, c] == j]
 
-    The chunk and N loops are static and small, so they unroll into
-    select-accumulate passes over the tile.  Each packed row is a ``(1,
+    Sᵀ is built in slices of :func:`slice_rows` rows, each from only the
+    groups it covers, and the slices are stacked along sublanes.  The
+    chunk and N loops are static and small, so they unroll into
+    select-accumulate passes over a slice.  Each packed row is a ``(1,
     cols)`` slice broadcast along sublanes, and the select runs in fp32 (the
     VPU's native width), so the same body lowers for f32, bf16 and int8
     values.  ``scales`` (optional) holds one ``(1, cols)`` fp32 row per
@@ -89,16 +122,20 @@ def _scatter_matrix(values_ref, indices_ref, m: int, scales=None):
     oracle's scatter-add.
     """
     chunk, n, cols = values_ref.shape
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk * m, cols), 0)
-    s = jnp.zeros((chunk * m, cols), jnp.float32)
-    for g in range(chunk):
-        for j in range(n):
-            v = values_ref[g, j:j + 1, :].astype(jnp.float32)
-            if scales is not None:
-                v = v * scales[g]
-            target = indices_ref[g, j:j + 1, :] + g * m
-            s = s + jnp.where(rows == target, v, 0.0)
-    return s
+    h = slice_rows(chunk * m, m)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0)
+    slices = []
+    for g0 in range(0, chunk, h // m):
+        s = jnp.zeros((h, cols), jnp.float32)
+        for g in range(g0, g0 + h // m):
+            for j in range(n):
+                v = values_ref[g, j:j + 1, :].astype(jnp.float32)
+                if scales is not None:
+                    v = v * scales[g]
+                target = indices_ref[g, j:j + 1, :] + (g - g0) * m
+                s = s + jnp.where(rows == target, v, 0.0)
+        slices.append(s)
+    return slices[0] if len(slices) == 1 else jnp.concatenate(slices, 0)
 
 
 def accumulate(out_ref, x, s, interpret: bool):

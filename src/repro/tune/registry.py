@@ -223,8 +223,10 @@ _INTERPRET_FLOP_LIMIT = 2 ** 26
 def _register_builtin_variants():
     # Imported lazily so `repro.tune.registry` never forces Pallas at import.
     from repro.kernels import ref as kref
-    from repro.kernels.demm_spmm import (LANES, SUBLANES, demm_spmm_pallas,
-                                         demm_xwT_pallas, fit_tile)
+    from repro.kernels.demm_spmm import (LANES, SUBLANES,
+                                         count_scatter_slices,
+                                         demm_spmm_pallas, demm_xwT_pallas,
+                                         fit_tile, group_chunk)
 
     def _legal_tiles(dim: int, lo: int, hi: int, align: int):
         return tuple(dict.fromkeys(
@@ -235,6 +237,8 @@ def _register_builtin_variants():
 
     def xwT_pallas_call(x, values, indices, cfg, w_shape, *,
                         interpret, block_b=128, block_o=128, **_):
+        count_scatter_slices("xwT", group_chunk(values.shape[0], cfg.m),
+                             cfg.m)
         return demm_xwT_pallas(x, values, indices, cfg, block_b=block_b,
                                block_o=block_o, interpret=interpret)
 
@@ -273,6 +277,8 @@ def _register_builtin_variants():
 
     def spmm_pallas_call(values, indices, b, cfg, a_shape, *,
                          interpret, block_r=128, block_c=256, **_):
+        count_scatter_slices("spmm", group_chunk(values.shape[0], cfg.m),
+                             cfg.m)
         return demm_spmm_pallas(values, indices, b, cfg, block_r=block_r,
                                 block_c=block_c, interpret=interpret)
 
@@ -321,6 +327,7 @@ def _register_builtin_variants():
                              f"{r} % {block_r}")
         dense = np.asarray(unpack(values, indices, cfg, tuple(a_shape)))
         ag, vals, idxs, _ = pack_block_sparse(dense, cfg, block_r=block_r)
+        count_scatter_slices("spmm", 1, cfg.m)
         return demm_block_spmm_pallas(
             jax.numpy.asarray(ag), jax.numpy.asarray(vals),
             jax.numpy.asarray(idxs), b, cfg, r=r, cd_block=cd_block,
@@ -368,6 +375,7 @@ def _register_builtin_variants():
                               w_shape, *, interpret, cd_block=256, **_):
         from repro.kernels.demm_block_spmm import demm_block_xwT_pallas
 
+        count_scatter_slices("xwT_block", 1, cfg.m)
         return demm_block_xwT_pallas(x, values, indices, active_groups, cfg,
                                      cd_block=int(cd_block),
                                      interpret=interpret)
@@ -411,6 +419,8 @@ def _register_builtin_variants():
 
     def xwT_q8_pallas_call(x, values, indices, scales, cfg, w_shape, *,
                            interpret, block_b=128, block_o=128, **_):
+        count_scatter_slices("xwT_q8", group_chunk(values.shape[0], cfg.m),
+                             cfg.m)
         return demm_xwT_q8_pallas(x, values, indices, scales, cfg,
                                   block_b=block_b, block_o=block_o,
                                   interpret=interpret)
@@ -443,6 +453,7 @@ def _register_builtin_variants():
     def xwT_block_q8_pallas_call(x, values, indices, active_groups, scales,
                                  cfg, w_shape, *, interpret, cd_block=256,
                                  **_):
+        count_scatter_slices("xwT_block_q8", 1, cfg.m)
         return demm_block_xwT_q8_pallas(x, values, indices, active_groups,
                                         scales, cfg, cd_block=int(cd_block),
                                         interpret=interpret)

@@ -119,6 +119,25 @@ def test_serving_kernel_compiles_for_v5e(one_chip, op, pattern):
                 op, shape, batch, mem)
 
 
+# The chat cell's served mapping (benchmarks/chip/configs/stablelm_3b.json):
+# density 1/16 as 5:80 on K=2560 and 3:48 on K=6912, eight groups a grid
+# step, at its 16 decode slots and 256-token prefill chunk.
+CHAT_GROUPS = {D_MODEL: (5, 80), D_FF: (3, 48)}
+CHAT_BATCHES = (16, 256)
+
+
+@pytest.mark.parametrize("op", ["xwT", "xwT_q8"])
+def test_chat_cell_mapping_compiles_for_v5e(one_chip, op):
+    for shape in SHAPES:
+        cfg = SparsityConfig(*CHAT_GROUPS[shape[1]])
+        for batch in CHAT_BATCHES:
+            compiled = compile_op(op, shape, batch, cfg, one_chip)
+            assert "tpu_custom_call" in compiled.as_text(), (shape, batch)
+            mem = compiled.memory_analysis()
+            assert mem.temp_size_in_bytes < shape[0] * shape[1] // 16, (
+                op, shape, batch, mem)
+
+
 @pytest.mark.parametrize("layout", ["xwT", "block"])
 def test_tp4_packed_decode_step_compiles_for_v5e(topo, layout):
     """The packed decode step under ``ShardingPlan(tp=4)`` on four described

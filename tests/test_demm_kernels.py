@@ -3,6 +3,8 @@
 Kernels run in interpret mode (CPU container; TPU is the lowering target).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,11 +15,14 @@ except ModuleNotFoundError:  # bare env: deterministic example replay
     from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.sparsity import SparsityConfig, pack, random_sparse_dense
+from repro.kernels import demm_q8 as q8_kernels
+from repro.kernels import demm_spmm as spmm_kernels
 from repro.kernels import ref as kref
 from repro.kernels.demm_block_spmm import (
     demm_block_spmm_pallas,
     pack_block_sparse,
 )
+from repro.kernels.demm_q8 import demm_xwT_q8_pallas
 from repro.kernels.demm_spmm import demm_spmm_pallas, demm_xwT_pallas
 from repro.kernels.ops import demm_matmul_xwT, demm_spmm
 
@@ -37,6 +42,11 @@ SWEEP = [
     (8, 128, 256, 1, 256, 128, 256, jnp.bfloat16),
     (1, 2, 16, 8, 32, 16, 32, jnp.float32),   # fine-grained 1:2
     (1, 4, 16, 4, 32, 16, 32, jnp.float32),   # fine-grained 1:4
+    # several groups a grid step, Sᵀ built in slices of lcm(M, 8) rows
+    (5, 80, 128, 16, 16, 128, 16, jnp.float32),   # 8 groups a step
+    (3, 48, 128, 8, 16, 128, 16, jnp.bfloat16),   # 8 groups a step
+    (2, 4, 128, 64, 16, 128, 16, jnp.float32),    # 2 groups a slice
+    (8, 16, 128, 16, 16, 128, 16, jnp.float32),
 ]
 
 
@@ -66,6 +76,68 @@ def test_xwT_kernel_vs_oracle(n, m, rows, groups, cd, br, bc, dtype):
                           block_b=min(bc, cd), block_o=br, interpret=True)
     want = kref.xwT_ref(xj, p.values, p.indices, cfg, (rows, groups * m))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_tol(dtype))
+
+
+def _full_tile_scatter(values_ref, indices_ref, m, scales=None):
+    """The Sᵀ expansion as it was before slicing, kept as the oracle: every
+    group's select passes sweep all ``chunk * M`` rows of the step."""
+    chunk, n, cols = values_ref.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk * m, cols), 0)
+    s = jnp.zeros((chunk * m, cols), jnp.float32)
+    for g in range(chunk):
+        for j in range(n):
+            v = values_ref[g, j:j + 1, :].astype(jnp.float32)
+            if scales is not None:
+                v = v * scales[g]
+            target = indices_ref[g, j:j + 1, :] + g * m
+            s = s + jnp.where(rows == target, v, 0.0)
+    return s
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+# (n, m, groups): chunk 8 at 5:80, 3:48 and 8:16 (one group a slice),
+# chunk 32 at 2:4 (two groups a slice), chunk 1 at 8:128 (unchanged)
+SLICED = [(5, 80, 16), (3, 48, 8), (2, 4, 64), (8, 16, 16), (8, 128, 2)]
+
+
+@pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("op", ["xwT", "xwT_q8"])
+@pytest.mark.parametrize("n,m,groups", SLICED)
+def test_sliced_scatter_bitwise_equals_full_tile(monkeypatch, n, m, groups,
+                                                 op, x_dtype):
+    """Building Sᵀ a slice at a time adds to each row exactly the terms the
+    full-tile sweep adds, in the same order, less its ``+0.0`` terms: the
+    kernels' outputs are bit for bit the full-tile expansion's.  Operands
+    repeat indices inside a group; the int8 kernel takes per-group
+    scales."""
+    rng = np.random.default_rng(n * 100 + m)
+    o, bx = 256, 8
+    cfg = SparsityConfig(n, m)
+    idx = rng.integers(0, m, (groups, n, o)).astype(np.int32)
+    if n > 1:
+        idx[:, 1, ::2] = idx[:, 0, ::2]            # repeated indices
+    x = rng.standard_normal((bx, groups * m))
+    if op == "xwT":
+        vals = rng.standard_normal((groups, n, o)).astype(np.float32)
+        vals[:, -1, ::3] = 0.0
+        kernel, operands = demm_xwT_pallas, [vals, idx]
+    else:
+        vals = rng.integers(-127, 128, (groups, n, o)).astype(np.int8)
+        scales = rng.uniform(0.001, 0.02, (groups, o)).astype(np.float32)
+        kernel, operands = demm_xwT_q8_pallas, [vals, idx, scales]
+    # The unjitted body, so each call traces the expansion patched in now.
+    call = functools.partial(
+        kernel.__wrapped__, jnp.asarray(x, x_dtype),
+        *map(jnp.asarray, operands), cfg, block_b=8, block_o=128,
+        interpret=True)
+    got = call()
+    monkeypatch.setattr(spmm_kernels, "_scatter_matrix", _full_tile_scatter)
+    monkeypatch.setattr(q8_kernels, "_scatter_matrix", _full_tile_scatter)
+    want = call()
+    np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
 @pytest.mark.parametrize("block_r", [8, 16, 32])

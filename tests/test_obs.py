@@ -248,6 +248,27 @@ def test_dispatch_counters_cover_all_packed_ops(fresh_default_registry):
     assert _dispatch_counts(reg)[("xwT", "reference")] == before
 
 
+def test_scatter_slice_counter_per_traced_pallas_call(fresh_default_registry):
+    """``kernel_scatter_slices_total`` records, per traced packed Pallas
+    call, the Sᵀ rows built at a time and per grid step: 5:80 takes eight
+    groups a step and builds them one group (80 rows) at a time; 8:128
+    takes one group a step, so its one slice is the whole step."""
+    rng = np.random.default_rng(0)
+    o, b = 128, 4
+    for pattern, k in (((5, 80), 640), ((8, 128), 256)):
+        sp = SparsityConfig(*pattern)
+        p = pack(jnp.asarray(random_sparse_dense(rng, o, k, sp)), sp)
+        pw = PackedWeight(p.values, p.indices, cfg=sp, dense_shape=(o, k))
+        jax.eval_shape(lambda xx: demm_matmul_packed(xx, pw,
+                                                     backend="pallas"),
+                       jax.ShapeDtypeStruct((b, k), jnp.float32))
+    got = {(c["labels"]["op"], c["labels"]["slice_rows"],
+            c["labels"]["chunk_rows"]): c["value"]
+           for c in fresh_default_registry.snapshot(meta=False)["counters"]
+           if c["name"] == "kernel_scatter_slices_total"}
+    assert got == {("xwT", "80", "640"): 1, ("xwT", "128", "128"): 1}
+
+
 # ---------------------------------------------------------------------------
 # tune-cache accounting + atomic save
 # ---------------------------------------------------------------------------
