@@ -52,8 +52,9 @@ class Options:
     engine_hook: Optional[Callable] = None
 
 
-def arch_config(config: dict):
-    """The program's ArchConfig for a configuration file."""
+def arch_config(config: dict, layer):
+    """The program's ArchConfig for a configuration file, with the layer
+    kind's own replacements applied last."""
     import dataclasses as dc
 
     from repro.configs.base import get_arch
@@ -73,6 +74,7 @@ def arch_config(config: dict):
         compute_dtype=config["compute_dtype"])
     if "head_dim" in config:
         changes["head_dim"] = int(config["head_dim"])
+    changes.update(layer.arch_changes(config))
     return dc.replace(get_arch(config["arch"]), **changes)
 
 
@@ -95,9 +97,10 @@ def build(cell: Cell, seed: int, opts: Options):
     from repro.paged import PagedServeConfig
     from repro.serve import make_engine
 
-    cfg = arch_config(cell.config)
+    cfg = arch_config(cell.config, cell.layer)
     model = build_model(cfg)
-    params = weights.build_served(model, cell.config, seed, pack_tree)
+    params = weights.build_served(model, cell.config, cell.layer, seed,
+                                  pack_tree)
     jax.block_until_ready(params)
     eng = cell.traffic["engine"]
     serve_cfg = PagedServeConfig(
@@ -173,13 +176,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from chipbench import device as device_mod
 
     mix, config = cell.traffic, cell.config
-    dims = weights.dims_of(config)
+    dims = weights.layer_dims(config, cell.layer)
     model, params, engine = build(cell, seed, opts)
     warm(engine, dims["vocab"])
     log(f"built and warmed in {time.monotonic() - t_start:.3f}s")
     if opts.engine_hook is not None:
         opts.engine_hook(engine)
-    kept = costs.kept_weights(params)
+    active = cell.layer.active_weights(params, dims)
 
     pool = traffic.build_pool(mix, seconds, seed)
     runner = Runner(engine, pool, mix, seed, dims["vocab"], make_request,
@@ -225,9 +228,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "dispatched": dispatched,
         "decode_step_sketch": stats.sketch_window(sk0, sk1),
         "trace": reduced,
-        "gen_flops_window": costs.token_flops(kept, dims, rec["gen_ctx"]),
+        "gen_flops_window": costs.token_flops(active, dims,
+                                              rec["gen_ctx"]),
         "prompt_flops_window": costs.prompt_flops(
-            kept, dims, rec["prompt_tokens_window"],
+            active, dims, rec["prompt_tokens_window"],
             rec["prompt_ctx_window"], rec["prefills_window"]),
         "memory_peak_bytes": memory_peak,
     })
@@ -238,6 +242,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     del runner, engine, params, model
     gc.collect()
     rec["check"] = check(cell, seed, samples, control=opts.control)
+    rec["samples"] = samples
     return rec
 
 
@@ -248,7 +253,7 @@ def check(cell: Cell, seed: int, samples, control: bool = False) -> dict:
         return {"served_logit_gap": {"value": math.inf, "limit": limit,
                                      "requests": 0, "tokens": 0}}
     t = time.monotonic()
-    got = reference.gaps(cell.config, seed, samples,
+    got = reference.gaps(cell.config, cell.layer, seed, samples,
                          cell.traffic["check"]["buckets"], control=control)
     widest = reference.Gaps.widest(got.served)
     log(f"reference over {len(samples)} requests "

@@ -9,9 +9,14 @@
   which is ``2 * rows * O * K * N / M``, the work the algorithm needs.
 * The least time of a call is the larger of operations over the chip's
   peak rate and bytes over its peak bandwidth.
-* A served token costs 2 operations per kept packed weight, 2 per weight
-  of the head over the true vocabulary, and ``4 * layers * Hq * Dh * ctx``
-  for attention over its ``ctx`` positions.
+* A served token costs 2 operations per kept packed weight it multiplies
+  (all of them in a dense layer; a layer kind counts its own with
+  ``active_weights``), 2 per weight of the head over the true vocabulary,
+  and ``4 * layers * Hq * Dh * ctx`` for attention over its ``ctx``
+  positions.
+
+:func:`kernel_call` counts a call of any kernel family that brings no
+``kernels/<family>.py`` of its own (:func:`chipbench.spec.load_kernel_call`).
 """
 
 from __future__ import annotations
@@ -95,7 +100,8 @@ def _head(dims: dict) -> float:
 
 def token_flops(kept: int, dims: dict, ctx: np.ndarray) -> float:
     """Operations to decode tokens whose attention spans ``ctx`` positions
-    each (one entry per token), the head included for each."""
+    each (one entry per token), the head included for each; ``kept`` is
+    the kept weights one token multiplies."""
     ctx = np.asarray(ctx, np.float64)
     return float((2.0 * kept + _head(dims)) * len(ctx)
                  + _attn(dims) * ctx.sum())

@@ -2,7 +2,8 @@
 
 A decoder forward pass in straightforward ``jax.numpy``, float32 at
 ``Precision.HIGHEST``, with no kernels, cache or batching: embedding,
-then per layer RMSNorm, rotary GQA attention (causal) and a SiLU-gated MLP,
+then each layer as the configuration's layer kind computes it (its
+``forward``, in ``layers/<kind>.py``, built from this module's helpers),
 then the final norm and the head.  It imports nothing of the program and
 takes nothing the program made: it draws the same weights from the seed
 with :mod:`chipbench.weights`, one layer at a time, so it runs in the
@@ -96,21 +97,11 @@ def _attention(q, k, v):
     return out.reshape(t, hq * dh)
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "low"))
-def _layer(w, h, dims, low):
-    dims = dict(dims)
-    t = h.shape[0]
-    hq, hkv, dh = dims["hq"], dims["hkv"], dims["dh"]
-    a = _rms(h, w["ln1"]["scale"], dims["eps"])
-    q = _mm(a, w["attn"]["wq"]["w"], low).reshape(t, hq, dh)
-    k = _mm(a, w["attn"]["wk"]["w"], low).reshape(t, hkv, dh)
-    v = _mm(a, w["attn"]["wv"]["w"], low).reshape(t, hkv, dh)
-    q, k = _rope(q, dims["theta"]), _rope(k, dims["theta"])
-    h = h + _mm(_attention(q, k, v), w["attn"]["wo"]["w"], low)
-    b = _rms(h, w["ln2"]["scale"], dims["eps"])
-    g = jax.nn.silu(_mm(b, w["mlp"]["gate"]["w"], low))
-    return h + _mm(g * _mm(b, w["mlp"]["up"]["w"], low),
-                   w["mlp"]["down"]["w"], low)
+@functools.partial(jax.jit, static_argnames=("forward", "dims", "low"))
+def _run_layer(w, h, forward, dims, low):
+    """A layer kind's ``forward``, compiled once per kind, sizes and
+    precision (``dims`` as sorted items)."""
+    return forward(w, h, dict(dims), low)
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "low"))
@@ -141,18 +132,20 @@ class Gaps:
         return float(max(float(np.max(g)) for g in per_request))
 
 
-def gaps(config: dict, seed: int, samples: Sequence[Served],
+def gaps(config: dict, layer, seed: int, samples: Sequence[Served],
          buckets: Sequence[int], control: bool = False) -> Gaps:
     """Run the reference (and with ``control`` the float8 pass beside it)
-    over every sample, layer by layer."""
-    dims = weights.dims_of(config)
+    over every sample, layer by layer; ``layer`` is the configuration's
+    layer kind (:func:`chipbench.spec.layer_of`)."""
+    dims = weights.layer_dims(config, layer)
     groups = weights.groups_of(config)
     logit_std = float(config["logit_std"])
     key = weights.seed_key(seed)
     frozen = tuple(sorted(dims.items()))
+    tree = layer.tree(dims)
     top = jax.jit(lambda k: weights.top_weights(k, dims, groups,
                                                 logit_std))(key)
-    gen = jax.jit(lambda k, i: weights.layer_weights(k, i, dims, groups,
+    gen = jax.jit(lambda k, i: weights.layer_weights(k, i, tree, groups,
                                                      logit_std))
     seqs, rows = [], []
     for s in samples:
@@ -169,10 +162,10 @@ def gaps(config: dict, seed: int, samples: Sequence[Served],
         rows.append(np.concatenate([want, np.full(pad, want[-1])]))
     hs = [top["embed"]["table"][t] for t in seqs]
     lows = list(hs) if control else []
-    for layer in range(dims["layers"]):
-        w = gen(key, np.uint32(layer))
-        hs = [_layer(w, h, frozen, False) for h in hs]
-        lows = [_layer(w, h, frozen, True) for h in lows]
+    for i in range(dims["layers"]):
+        w = gen(key, np.uint32(i))
+        hs = [_run_layer(w, h, layer.forward, frozen, False) for h in hs]
+        lows = [_run_layer(w, h, layer.forward, frozen, True) for h in lows]
         del w
     served, ctrl = [], []
     for i, s in enumerate(samples):

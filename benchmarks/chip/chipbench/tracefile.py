@@ -13,6 +13,10 @@ it can be checked on a small recorded trace:
 * ``kernel_s``  — the summed device time of the operations whose own HLO
   name starts with ``demm`` (the Pallas kernels are named ``demm_*``), and
   ``kernel_least_s`` the least time the same calls could take;
+* ``kernels``   — the same by kernel family, the op name up to its first
+  ``.`` (``demm_xwT.46`` is a ``demm_xwT``): ``s``, ``least_s`` and
+  ``calls``.  A family's calls are costed by ``kernels/<family>.py`` where
+  the benchmark has that file, else by :func:`chipbench.costs.kernel_call`;
 * ``device_ops`` — self time by operation (its time less the time of the
   operations nested in it, as a ``while`` holds its body), largest first;
 * ``idle_gaps`` — the window's idle time by the innermost host span that
@@ -31,6 +35,8 @@ import dataclasses
 import glob
 import os
 from typing import Dict, List, Optional, Tuple
+
+from chipbench import costs, spec
 
 KERNEL_MARK = "demm"
 WINDOW_SPAN = "bench.window"
@@ -135,6 +141,27 @@ def is_kernel(name: str) -> bool:
     return name.startswith(KERNEL_MARK)
 
 
+def family(name: str) -> str:
+    """``demm_xwT.46`` -> ``demm_xwT``."""
+    return name.split(".", 1)[0]
+
+
+def least_times(kernels: Dict[str, str], peak: dict,
+                bench_dir: str) -> Dict[str, float]:
+    """The least time of each kernel op's call, from its HLO text, by its
+    family's ``kernels/<family>.py`` or :func:`chipbench.costs.kernel_call`
+    where the family has none."""
+    calls: Dict[str, object] = {}
+    out = {}
+    for name, text in kernels.items():
+        fam = family(name)
+        if fam not in calls:
+            calls[fam] = (spec.load_kernel_call(bench_dir, fam)
+                          or costs.kernel_call)
+        out[name] = calls[fam](text).least_s(peak)
+    return out
+
+
 def self_times(events: List[Tuple[str, int, int]]) -> Dict[str, int]:
     """Time of each operation less the time of the operations nested in
     it, summed by name."""
@@ -171,14 +198,14 @@ def _span_at(spans: List[Tuple[str, int, int]], starts: List[int],
 
 
 def reduce(trace: Trace, peak: Optional[dict] = None,
-           window: Optional[Interval] = None, top: int = 10) -> dict:
+           window: Optional[Interval] = None, top: int = 10,
+           bench_dir: str = spec.BENCH_DIR) -> dict:
     """The traced window's numbers.  The window ends no later than the
     last recorded device operation: a profiler whose event buffer fills
     records nothing after it, and that silence is not idle time.  With
     ``peak``, ``kernel_least_s`` sums the least time of every kernel call
-    in the window (:func:`chipbench.costs.kernel_call`)."""
-    from chipbench import costs
-
+    in the window (:func:`least_times`, from the kernel cost files under
+    ``bench_dir``, the benchmark's own by default)."""
     lo, hi = window or window_of(trace)
     last = max((e for ev in trace.devices.values() for _, _, e in ev),
                default=hi)
@@ -186,9 +213,9 @@ def reduce(trace: Trace, peak: Optional[dict] = None,
     if hi <= lo:
         raise ValueError(f"empty traced window {lo}..{hi}")
     n_dev = max(len(trace.devices), 1)
-    least = {name: costs.kernel_call(text).least_s(peak)
-             for name, text in trace.kernels.items()} if peak else {}
-    busy_ns, kernel_ns, kernel_events, least_s = 0, 0, 0, 0.0
+    least = least_times(trace.kernels, peak, bench_dir) if peak else {}
+    busy_ns = 0
+    by_family: Dict[str, list] = {}     # family -> [ns, least_s, calls]
     by_op: Dict[str, int] = {}
     gaps: List[Interval] = []
     for dev, events in trace.devices.items():
@@ -199,9 +226,10 @@ def reduce(trace: Trace, peak: Optional[dict] = None,
                 continue
             clipped.append((name, s, e))
             if is_kernel(name):
-                kernel_ns += e - s
-                kernel_events += 1
-                least_s += least.get(name, 0.0)
+                fam = by_family.setdefault(family(name), [0, 0.0, 0])
+                fam[0] += e - s
+                fam[1] += least.get(name, 0.0)
+                fam[2] += 1
         for name, t in self_times(clipped).items():
             by_op[name] = by_op.get(name, 0) + t
         intervals = [(s, e) for _, s, e in clipped]
@@ -228,9 +256,12 @@ def reduce(trace: Trace, peak: Optional[dict] = None,
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy_ns / n_dev / 1e9,
-        "kernel_s": kernel_ns / n_dev / 1e9,
-        "kernel_events": kernel_events,
-        "kernel_least_s": least_s / n_dev,
+        "kernel_s": sum(f[0] for f in by_family.values()) / n_dev / 1e9,
+        "kernel_events": sum(f[2] for f in by_family.values()),
+        "kernel_least_s": sum(f[1] for f in by_family.values()) / n_dev,
+        "kernels": {fam: {"s": ns / n_dev / 1e9, "least_s": lst / n_dev,
+                          "calls": calls}
+                    for fam, (ns, lst, calls) in sorted(by_family.items())},
         "device_ops": [[k, v / n_dev / 1e9] for k, v in ranked],
         "idle_gaps": [[k, v / n_dev / 1e9] for k, v in ranked_idle],
         "gaps": len(gaps),

@@ -11,10 +11,15 @@ of a full-width model never exists whole.
 Each sparse linear is a normal matrix pruned by magnitude to the N:M
 pattern the configuration file states for its contraction dim, scaled so
 rows have unit expected squared norm; the program's packing then keeps
-exactly those weights.  Norm scales are ``1 + 0.1 N(0, 1)``, the embedding
-is ``N(0, 1)`` and the head is scaled so logits have a standard deviation
-of about ``logit_std``.
-"""
+exactly those weights.  A stack of linears, ``w`` of shape ``(..., O, K)``
+(a layer's experts, say), draws each matrix by the same rule from its own
+key: the path's key folded with the matrix's flat index.  Norm scales are
+``1 + 0.1 N(0, 1)``, the embedding is ``N(0, 1)`` and the head is scaled
+so logits have a standard deviation of about ``logit_std``.
+
+A layer's tree, its names and shapes with a ``sparsity`` marker on each
+pruned linear, comes from the configuration's layer kind
+(``layers/<kind>.py``, :func:`chipbench.spec.layer_of`)."""
 
 from __future__ import annotations
 
@@ -73,20 +78,35 @@ def sparse_linear(key, o: int, k: int, n: int, m: int) -> jax.Array:
     return jnp.where(topn_mask(w, n, m), w * np.float32(scale), 0.0)
 
 
+def _linear(key, shape, path: str, sparse: bool, groups: Groups):
+    """The ``w`` of a linear node: ``(O, K)``, or a stack ``(..., O, K)``
+    whose every leading index is drawn from ``fold_in(key, index)`` by the
+    same rule (one expert of a stack, say)."""
+    *lead, o, k = shape
+    if sparse:
+        if k not in groups:
+            raise KeyError(f"{path}: the configuration file states no "
+                           f"N:M group for contraction dim {k}")
+        n, m = groups[k]
+
+        def draw(sub):
+            return sparse_linear(sub, o, k, n, m)
+    else:
+        def draw(sub):
+            return jax.random.normal(sub, (o, k), jnp.float32) / np.sqrt(k)
+    if not lead:
+        return draw(key)
+    stack = jax.lax.map(lambda i: draw(jax.random.fold_in(key, i)),
+                        jnp.arange(int(np.prod(lead)), dtype=jnp.uint32))
+    return stack.reshape(*lead, o, k)
+
+
 def _fill(key, node, path: str, groups: Groups, logit_std: float):
     """A weight tree shaped like ``node`` (eval_shape output, Static
     metadata kept), every array drawn from ``key`` and its path."""
     if isinstance(node, dict) and "w" in node:
-        o, k = node["w"].shape
-        sub = _path_key(key, path)
-        if "sparsity" in node:
-            if k not in groups:
-                raise KeyError(f"{path}: the configuration file states no "
-                               f"N:M group for contraction dim {k}")
-            n, m = groups[k]
-            w = sparse_linear(sub, o, k, n, m)
-        else:
-            w = jax.random.normal(sub, (o, k), jnp.float32) / np.sqrt(k)
+        w = _linear(_path_key(key, path), node["w"].shape, path,
+                    "sparsity" in node, groups)
         return {**node, "w": w}
     if isinstance(node, dict):
         return {name: _fill(key, child, f"{path}/{name}", groups, logit_std)
@@ -105,8 +125,9 @@ def _fill(key, node, path: str, groups: Groups, logit_std: float):
 
 
 def dims_of(config: dict) -> dict:
-    """The sizes the benchmark's own weight tree and reference use, from
-    the configuration file alone."""
+    """The sizes every layer kind's weight tree and reference use, from
+    the configuration file alone (a layer kind adds its own:
+    :func:`layer_dims`)."""
     d, hq = int(config["hidden_size"]), int(config["num_attention_heads"])
     v = int(config["vocab_size"])
     return {"layers": int(config["num_hidden_layers"]), "d": d,
@@ -119,22 +140,13 @@ def dims_of(config: dict) -> dict:
             "eps": float(config["rms_norm_eps"])}
 
 
+def layer_dims(config: dict, layer) -> dict:
+    """:func:`dims_of` with what the configuration's layer kind adds."""
+    return layer.dims(config, dims_of(config))
+
+
 def _sds(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.float32)
-
-
-def layer_tree(dims: dict) -> dict:
-    """Shapes of one decoder layer, by the names the served tree uses."""
-    d, ff, dh = dims["d"], dims["ff"], dims["dh"]
-    q, kv = dims["hq"] * dh, dims["hkv"] * dh
-
-    def lin(o, k):
-        return {"w": _sds(o, k), "sparsity": "stated by the config file"}
-
-    return {"ln1": {"scale": _sds(d)}, "ln2": {"scale": _sds(d)},
-            "attn": {"wq": lin(q, d), "wk": lin(kv, d), "wv": lin(kv, d),
-                     "wo": lin(d, q)},
-            "mlp": {"gate": lin(ff, d), "up": lin(ff, d), "down": lin(d, ff)}}
 
 
 def top_tree(dims: dict) -> dict:
@@ -151,7 +163,7 @@ def _shape_map(tree) -> dict:
 
 
 def _arrays_only(tree):
-    """``tree`` without the sparsity markers of :func:`layer_tree`."""
+    """``tree`` without the sparsity markers of a layer kind's ``tree``."""
     if isinstance(tree, dict):
         return {k: _arrays_only(v) for k, v in tree.items()
                 if not isinstance(v, str)}
@@ -162,12 +174,12 @@ def layer_key(key, layer):
     return jax.random.fold_in(_path_key(key, "layers"), layer)
 
 
-def layer_weights(key, layer, dims: dict, groups: Groups,
+def layer_weights(key, layer, tree: dict, groups: Groups,
                   logit_std: float) -> dict:
-    """Dense (pruned) float32 weights of one layer."""
-    tree = _fill(layer_key(key, layer), layer_tree(dims), "", groups,
-                 logit_std)
-    return _arrays_only(tree)
+    """Dense (pruned) float32 weights of layer ``layer``, shaped like
+    ``tree`` (a layer kind's ``tree(dims)``)."""
+    return _arrays_only(_fill(layer_key(key, layer), tree, "", groups,
+                              logit_std))
 
 
 def top_weights(key, dims: dict, groups: Groups, logit_std: float) -> dict:
@@ -175,20 +187,20 @@ def top_weights(key, dims: dict, groups: Groups, logit_std: float) -> dict:
     return _fill(key, top_tree(dims), "", groups, logit_std)
 
 
-def served_builder(model, config: dict, pack_layer):
+def served_builder(model, config: dict, layer, pack_layer):
     """``build(key)``: the program's params, every layer drawn and handed
     to ``pack_layer`` (the program's packing) inside a ``lax.map``, so one
     dense layer is live at a time.  The program's parameter tree must have
-    exactly the benchmark's names and shapes, else the reference would
-    compute another model."""
-    dims, groups = dims_of(config), groups_of(config)
+    exactly the names and shapes of the layer kind's ``tree``, else the
+    reference would compute another model."""
+    dims, groups = layer_dims(config, layer), groups_of(config)
     logit_std = float(config["logit_std"])
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     layer_shapes = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
         shapes["layers"])
     top = {k: v for k, v in shapes.items() if k != "layers"}
-    for got, want in ((layer_shapes, layer_tree(dims)),
+    for got, want in ((layer_shapes, layer.tree(dims)),
                       (top, top_tree(dims))):
         if _shape_map(got) != _shape_map(want):
             raise ValueError(f"the program's parameter tree "
@@ -208,6 +220,7 @@ def served_builder(model, config: dict, pack_layer):
     return build
 
 
-def build_served(model, config: dict, seed: int, pack_layer):
+def build_served(model, config: dict, layer, seed: int, pack_layer):
     """The program's params on the device, in one jitted call."""
-    return jax.jit(served_builder(model, config, pack_layer))(seed_key(seed))
+    return jax.jit(served_builder(model, config, layer, pack_layer))(
+        seed_key(seed))

@@ -42,4 +42,5 @@ def cell(limit: float):
     return spec.Cell(name="tiny.chat", chips=1, config_name="tiny",
                      config=CONFIG, traffic_name="tiny", traffic=MIX,
                      limits={"served_logit_gap": {"limit": limit}},
-                     end_to_end=[], per_layer=[])
+                     end_to_end=[], per_layer=[],
+                     layer=spec.layer_of(CONFIG))

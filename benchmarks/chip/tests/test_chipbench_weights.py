@@ -9,10 +9,11 @@ import pytest
 
 import chipbench_tiny
 from chipbench import cell as cell_mod
-from chipbench import costs, weights
+from chipbench import costs, spec, weights
 
 CFG = chipbench_tiny.CONFIG
 BIG_SEED = 2**33 + 99
+DENSE = spec.layer_of(CFG)
 
 
 @pytest.fixture(scope="module")
@@ -20,8 +21,9 @@ def served():
     from repro.launch.pack_tree import pack_tree
     from repro.models.families import build_model
 
-    model = build_model(cell_mod.arch_config(CFG))
-    return model, weights.build_served(model, CFG, BIG_SEED, pack_tree)
+    model = build_model(cell_mod.arch_config(CFG, DENSE))
+    return model, weights.build_served(model, CFG, DENSE, BIG_SEED,
+                                       pack_tree)
 
 
 def test_pattern_and_scale():
@@ -45,7 +47,8 @@ def test_program_packing_keeps_exactly_the_benchmarks_weights(served):
     dims, groups = weights.dims_of(CFG), weights.groups_of(CFG)
     key = weights.seed_key(BIG_SEED)
     for layer in range(dims["layers"]):
-        want = weights.layer_weights(key, layer, dims, groups, 2.0)
+        want = weights.layer_weights(key, layer, DENSE.tree(dims), groups,
+                                     2.0)
         for block, names in (("attn", ("wq", "wk", "wv", "wo")),
                              ("mlp", ("gate", "up", "down"))):
             for name in names:
@@ -66,8 +69,9 @@ def test_program_packing_keeps_exactly_the_benchmarks_weights(served):
 
 def test_seed_changes_the_weights():
     dims, groups = weights.dims_of(CFG), weights.groups_of(CFG)
-    a = weights.layer_weights(weights.seed_key(1), 0, dims, groups, 2.0)
-    b = weights.layer_weights(weights.seed_key(2**32 + 1), 0, dims, groups,
+    tree = DENSE.tree(dims)
+    a = weights.layer_weights(weights.seed_key(1), 0, tree, groups, 2.0)
+    b = weights.layer_weights(weights.seed_key(2**32 + 1), 0, tree, groups,
                               2.0)
     assert not np.array_equal(np.asarray(a["mlp"]["up"]["w"]),
                               np.asarray(b["mlp"]["up"]["w"]))

@@ -108,8 +108,9 @@ def test_percentiles_cover_every_sample():
     gaps = [b - a for tr in runner.all
             for a, b in zip(tr.stamps, tr.stamps[1:]) if t0 <= b <= t1]
     assert len(gaps) == len(rec["token_gaps_s"]) > 100
-    assert _read("itl_p95_ms", rec) == pytest.approx(
+    assert _read("token_gap_p95_ms", rec) == pytest.approx(
         1000 * np.percentile(gaps, 95))
+    assert _read("itl_mean_ms", rec) == pytest.approx(1000 * np.mean(gaps))
     due = [t for t in runner.all if t0 <= t.due < t1]
     assert rec["attempted"] == len(due) and rec["failed"] == 0
     ttft = [t.first_token - t.due for t in due]
@@ -131,7 +132,8 @@ def test_rate_is_over_the_whole_window():
 
 @pytest.mark.parametrize("metric,loop,worse", [
     ("ttft_p50_ms", "open", "higher"),
-    ("itl_p95_ms", "open", "higher"),
+    ("itl_mean_ms", "open", "higher"),
+    ("token_gap_p95_ms", "open", "higher"),
     ("queue_wait_p50_ms", "open", "higher"),
     ("prompt_tokens_per_s", "closed", "lower"),
 ])

@@ -74,8 +74,9 @@ def main(argv=None) -> int:
     cell = spec.resolve(args.workload)
     dev = topologies.get_topology_desc(platform="tpu",
                                        topology_name="v5e:2x2").devices[0]
-    model = build_model(cell_mod.arch_config(cell.config))
-    build = weights.served_builder(model, cell.config, pack_tree)
+    model = build_model(cell_mod.arch_config(cell.config, cell.layer))
+    build = weights.served_builder(model, cell.config, cell.layer,
+                                   pack_tree)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     params = _on(dev, jax.eval_shape(build, key))
     if "build" in want:
@@ -102,18 +103,19 @@ def main(argv=None) -> int:
                           jax.ShapeDtypeStruct((), i32)))
         _report("prefill", chunk.lower(params, state, *args_).compile())
     if "reference" in want:
-        dims = weights.dims_of(cell.config)
+        dims = weights.layer_dims(cell.config, cell.layer)
         frozen = tuple(sorted(dims.items()))
         groups = weights.groups_of(cell.config)
+        tree = cell.layer.tree(dims)
         layer = _on(dev, jax.eval_shape(lambda k: weights.layer_weights(
-            k, 0, dims, groups, 1.0), key))
+            k, 0, tree, groups, 1.0), key))
         top = _on(dev, jax.eval_shape(lambda k: weights.top_weights(
             k, dims, groups, 1.0), key))
         t = max(cell.traffic["check"]["buckets"])
         h = _on(dev, jax.ShapeDtypeStruct((t, dims["d"]), jnp.float32))
         rows = _on(dev, jax.ShapeDtypeStruct((512,), i32))
-        _report("reference_layer", reference._layer.lower(
-            layer, h, frozen, False).compile())
+        _report("reference_layer", reference._run_layer.lower(
+            layer, h, cell.layer.forward, frozen, False).compile())
         _report("reference_head", reference._head.lower(
             top, h, rows, frozen, False).compile())
     return 0
