@@ -14,7 +14,12 @@ in this order; any failure raises and the script exits non-zero:
    2560x6912, attention 2560x2560), decode batch 4 and prefill chunk 32, at
    8:128 and the fine patterns 8:16, 2:16, 2:4.  Each result is compared
    with the op's ``reference`` backend on the same chip:
-   ``max|y - y_ref| / max|y_ref| <= KERNEL_RTOL``.
+   ``max|y - y_ref| / max|y_ref| <= KERNEL_RTOL``.  The grouped expert
+   kernel ``experts`` (``demm_experts``) is checked the same way at
+   OLMoE-1B-7B widths: 64 packed 8:128 experts of (O, K) (1024, 2048) and
+   (2048, 1024), over 32 rows (a 4-slot decode step, top-8) and 2048 rows
+   (a 256-token prefill chunk), each once routed top-8 from random logits
+   and once with every row on one expert (the other 63 empty).
 3. Serving (one chip): ``repro.launch.serve.run_serve`` serves stablelm_3b
    at full width (32 layers, d_model 2560, d_ff 6912, vocab 50304; random
    weights from seed 0, packed in one jitted init-and-pack program) through
@@ -71,6 +76,12 @@ PATTERNS = ("8:128", "8:16", "2:16", "2:4")
 KERNEL_BACKEND = {"xwT": "pallas", "xwT_q8": "pallas",
                   "xwT_block": "block_spmm", "xwT_block_q8": "block_spmm"}
 BLOCK_R = 128
+# OLMoE-1B-7B's expert stacks: 64 experts, gate/up (1024, 2048), down
+# (2048, 1024); rows of a 4-slot decode step and of a 256-token chunk
+EXPERTS = 64
+EXPERT_SHAPES = {"experts_gate": (1024, 2048), "experts_down": (2048, 1024)}
+EXPERT_ROWS = (32, 2048)
+EXPERT_TOP_K = 8
 
 SERVE = dict(requests=8, slots=4, max_new=16)
 
@@ -160,6 +171,74 @@ def kernel_phase(patterns=PATTERNS, shapes=SHAPES, batches=BATCHES,
         log(f"kernel {op:<13} backend={backend:<10} cases={cases} "
             f"max_rel_err={worst:.3e} bound={KERNEL_RTOL} "
             f"seconds={time.time() - t0:.1f}")
+
+
+def expert_routings(rows, experts, top_k, seed):
+    """Group sizes of ``rows`` sorted rows: a top-``top_k`` routing of
+    ``rows // top_k`` tokens over random router logits, and every row on
+    one expert."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    logits = rng.standard_normal((rows // top_k, experts))
+    top = np.argsort(-logits, axis=1)[:, :top_k]
+    one = np.zeros(experts, np.int32)
+    one[experts // 3] = rows
+    return {"top%d" % top_k: np.bincount(top.ravel(), minlength=experts)
+            .astype(np.int32), "one": one}
+
+
+def experts_phase(shapes=EXPERT_SHAPES, rows=EXPERT_ROWS, experts=EXPERTS,
+                  top_k=EXPERT_TOP_K, pattern="8:128", backend="pallas"):
+    """The grouped expert kernel vs the ``experts`` reference backend
+    (``kernels/ref.py``) on random packed stacks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro import tune
+    from repro.core.sparsity import SparsityConfig
+
+    t0 = time.time()
+    n, m = (int(v) for v in pattern.split(":"))
+    cfg = SparsityConfig(n, m)
+    kern = tune.get_variant("experts", backend)
+    ref = tune.get_variant("experts", "reference")
+    worst, cases, empty = 0.0, 0, 0
+    for name, (o, k) in shapes.items():
+        for r in rows:
+            params = kern.default_params(tune.Problem.for_experts(
+                (r, k), (o, k), cfg, jnp.bfloat16, experts))
+            for routing, sizes in expert_routings(r, experts, top_k,
+                                                  cases).items():
+                rng = np.random.default_rng(cases)
+                core = (experts, k // m, n, o)
+                x = jnp.asarray(rng.standard_normal((r, k), np.float32),
+                                jnp.bfloat16)
+                vals = jnp.asarray(rng.standard_normal(core, np.float32))
+                idx = jnp.asarray(rng.integers(0, m, core, np.int32))
+
+                def case(*args, shape=(o, k), params=params):
+                    y = kern.call(*args, cfg, shape, **params)
+                    y_ref = ref.call(*args, cfg, shape)
+                    return (jnp.max(jnp.abs(y - y_ref)),
+                            jnp.max(jnp.abs(y_ref)),
+                            jnp.all(jnp.isfinite(y)))
+
+                err, mag, finite = jax.jit(case)(x, jnp.asarray(sizes),
+                                                 vals, idx)
+                rel = float(err) / max(float(mag), 1e-30)
+                cases += 1
+                empty += int(np.sum(sizes == 0))
+                if not bool(finite) or not rel <= KERNEL_RTOL:
+                    raise AssertionError(
+                        f"experts/{backend} {pattern} {name}{(o, k)} rows "
+                        f"{r} routing {routing}: rel err {rel:.3e} > "
+                        f"{KERNEL_RTOL} (finite={bool(finite)})")
+                worst = max(worst, rel)
+    log(f"kernel {'experts':<13} backend={backend:<10} cases={cases} "
+        f"empty_experts={empty} max_rel_err={worst:.3e} "
+        f"bound={KERNEL_RTOL} seconds={time.time() - t0:.1f}")
 
 
 def dispatch_counts():
@@ -402,6 +481,7 @@ def main(argv=None) -> int:
         four_chip_phase(*build_model())
     else:
         kernel_phase()
+        experts_phase()
         serve_phase(*build_model())
     log(f"all phases passed in {time.time() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": {

@@ -22,6 +22,8 @@ class MoEConfig:
     d_ff_expert: int
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # renormalise the top-k routing weights to sum to 1 (OLMoE does not)
+    norm_topk_prob: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +54,10 @@ class ArchConfig:
     local_global_ratio: int = 0      # gemma3: 5 (5 local : 1 global)
     local_window: int = 1024
     rope_theta: float = 10000.0
+    # RMSNorm over the whole q and k projections, before the heads split
+    # and before RoPE (OLMoE)
+    qk_norm: bool = False
+    norm_eps: float = 1e-6           # every RMSNorm of the decoder
     # encoder-decoder (audio family)
     encoder_layers: int = 0
     encoder_seq_divisor: int = 4     # frames = seq_len // divisor

@@ -140,6 +140,33 @@ def demm_matmul_packed(x: jax.Array, pw: PackedWeight,
                            backend, pw.shards)
 
 
+def demm_experts_packed(x: jax.Array, group_sizes: jax.Array,
+                        pw: PackedWeight,
+                        backend: str = "reference") -> jax.Array:
+    """y[r] = x[r] @ W_{e(r)}ᵀ for the rows of ``x`` sorted by expert
+    (``group_sizes[e]`` consecutive rows for expert ``e``) over an
+    unstacked expert stack ``pw`` (values ``(E, G, Ne, O)``, the float
+    ``xwT`` layout).  Dispatch routes through the ``experts`` op of the
+    ``repro.tune`` registry; the serving path only (no custom_vjp)."""
+    from repro import obs, tune
+
+    if (pw.layout != LAYOUT_XWT or pw.qdtype is not None
+            or getattr(pw.values, "ndim", 4) != 4):
+        raise ValueError(
+            f"demm_experts_packed needs a float xwT expert stack of values "
+            f"(E, G, Ne, O), got a {pw.layout} weight of values "
+            f"{pw.values.shape}")
+    params = {}
+    if backend == "auto":
+        choice = tune.resolve_experts(x.shape, pw, x.dtype)
+        backend, params = choice.backend, choice.params
+    variant = tune.get_variant("experts", backend)
+    _count_dispatch("experts", backend)
+    with obs.annotate(f"demm/experts/{backend}"):
+        return variant.call(x, group_sizes, pw.values, pw.indices, pw.cfg,
+                            tuple(pw.dense_shape), **params)
+
+
 def _demm_matmul_sharded(x: jax.Array, pw: PackedWeight,
                          backend: str = "reference") -> jax.Array:
     """y = x @ W^T over a shard-stacked row-parallel weight.

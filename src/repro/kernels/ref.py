@@ -30,6 +30,29 @@ def xwT_ref(x: jax.Array, values: jax.Array, indices: jax.Array,
                    preferred_element_type=jnp.float32)
 
 
+def experts_ref(x: jax.Array, group_sizes: jax.Array, values: jax.Array,
+                indices: jax.Array, cfg: SparsityConfig, w_shape) -> jax.Array:
+    """y[r] = x[r] @ W_{e(r)}ᵀ for rows sorted by expert (``group_sizes[e]``
+    rows of expert ``e``, in order) over a packed stack ``(E, G, Ne, O)``:
+    one expert at a time, unpacked to dense, its product of every row kept
+    on the rows that are its own."""
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(x.shape[0])[:, None]
+    xf = x.astype(jnp.float32)
+
+    def one(y, expert):
+        v, i, lo, hi = expert
+        w = unpack(v, i, cfg, tuple(w_shape)).astype(jnp.float32)
+        mine = (row >= lo) & (row < hi)
+        return jnp.where(mine, jnp.dot(xf, w.T,
+                                       preferred_element_type=jnp.float32),
+                         y), None
+
+    y0 = jnp.zeros((x.shape[0], w_shape[0]), jnp.float32)
+    y, _ = jax.lax.scan(one, y0, (values, indices, ends - group_sizes, ends))
+    return y
+
+
 def block_spmm_ref(active_groups, values, indices, b, cfg: SparsityConfig,
                    r: int) -> jax.Array:
     """Oracle for the two-level block-sparse format: scatter every active

@@ -234,18 +234,26 @@ def scatter_chunk_pages(arena: jax.Array, row_table: jax.Array,
 # Full attention block (init + train/prefill/decode apply)
 # ---------------------------------------------------------------------------
 
-from repro.models.layers import apply_linear, apply_rope, init_linear  # noqa: E402
+from repro.models.layers import (apply_linear, apply_rmsnorm,  # noqa: E402
+                                 apply_rope, init_linear, init_rmsnorm)
 
 
 def init_attention(key, d: int, num_heads: int, num_kv_heads: int,
-                   head_dim: int, *, sparse=None, dtype=jnp.float32):
+                   head_dim: int, *, sparse=None, dtype=jnp.float32,
+                   qk_norm: bool = False):
+    """Q/K/V/O projections; ``qk_norm`` adds ``q_norm`` and ``k_norm``
+    RMSNorm scales over the whole q (Hq·Dh) and k (Hkv·Dh) projections."""
     kq, kk, kv, ko = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": init_linear(kq, d, num_heads * head_dim, sparse=sparse, dtype=dtype),
         "wk": init_linear(kk, d, num_kv_heads * head_dim, sparse=sparse, dtype=dtype),
         "wv": init_linear(kv, d, num_kv_heads * head_dim, sparse=sparse, dtype=dtype),
         "wo": init_linear(ko, num_heads * head_dim, d, sparse=sparse, dtype=dtype),
     }
+    if qk_norm:
+        p["q_norm"] = init_rmsnorm(num_heads * head_dim, dtype)
+        p["k_norm"] = init_rmsnorm(num_kv_heads * head_dim, dtype)
+    return p
 
 
 def _constrain_heads(x, *, seq_sharded=False):
@@ -282,12 +290,18 @@ def _constrain_heads(x, *, seq_sharded=False):
 
 
 def _project_qkv(params, x, kv_x, num_heads, num_kv_heads, head_dim,
-                 policy):
+                 policy, norm_eps=1e-6):
+    """q, k, v split into heads; where ``params`` hold ``q_norm`` and
+    ``k_norm`` (QK-norm), the whole q and k projections are RMS-normalised
+    first (eps ``norm_eps``), before the heads split and before RoPE."""
     b, t, _ = x.shape
     skv = kv_x.shape[1]
     q = apply_linear(params["wq"], x, policy=policy)
     k = apply_linear(params["wk"], kv_x, policy=policy)
     v = apply_linear(params["wv"], kv_x, policy=policy)
+    if "q_norm" in params:
+        q = apply_rmsnorm(params["q_norm"], q, norm_eps)
+        k = apply_rmsnorm(params["k_norm"], k, norm_eps)
     return (_constrain_heads(q.reshape(b, t, num_heads, head_dim)),
             _constrain_heads(k.reshape(b, skv, num_kv_heads, head_dim)),
             _constrain_heads(v.reshape(b, skv, num_kv_heads, head_dim)))
@@ -296,14 +310,14 @@ def _project_qkv(params, x, kv_x, num_heads, num_kv_heads, head_dim,
 def apply_attention(
     params, x, *, num_heads, num_kv_heads, head_dim, rope_theta,
     positions=None, causal=True, window=-1, static_window=None, kv_x=None,
-    policy=None, q_chunk=512, kv_chunk=1024,
+    policy=None, q_chunk=512, kv_chunk=1024, norm_eps=1e-6,
 ):
     """Self- (kv_x=None) or cross- (kv_x=encoder out, causal=False) attention."""
     b, t, _ = x.shape
     cross = kv_x is not None
     kv_src = kv_x if cross else x
     q, k, v = _project_qkv(params, x, kv_src, num_heads, num_kv_heads,
-                           head_dim, policy)
+                           head_dim, policy, norm_eps)
     if positions is None:
         positions = jnp.arange(t)
     if not cross:
@@ -318,14 +332,14 @@ def apply_attention(
 
 def apply_attention_decode(
     params, x, cache, pos, *, num_heads, num_kv_heads, head_dim, rope_theta,
-    window=-1, policy=None,
+    window=-1, policy=None, norm_eps=1e-6,
 ):
     """One-token decode.  cache: {"k": (B,S,Hkv,Dh), "v": ...}; pos: (B,)
     index at which to write the new KV (== current length).  Returns
     (out (B,1,D), new_cache)."""
     b = x.shape[0]
     q, k_new, v_new = _project_qkv(params, x, x, num_heads, num_kv_heads,
-                                   head_dim, policy)
+                                   head_dim, policy, norm_eps)
     q = apply_rope(q, pos[:, None], rope_theta)
     k_new = apply_rope(k_new, pos[:, None], rope_theta)
     onehot = jax.nn.one_hot(pos, cache["k"].shape[1],
@@ -346,6 +360,7 @@ def apply_attention_decode(
 def apply_attention_decode_paged(
     params, x, arena_k, arena_v, block_table, active, pos, *, num_heads,
     num_kv_heads, head_dim, rope_theta, window=-1, policy=None,
+    norm_eps=1e-6,
 ):
     """One-token decode against a paged KV arena (DESIGN.md §13).
 
@@ -359,7 +374,7 @@ def apply_attention_decode_paged(
     """
     b = x.shape[0]
     q, k_new, v_new = _project_qkv(params, x, x, num_heads, num_kv_heads,
-                                   head_dim, policy)
+                                   head_dim, policy, norm_eps)
     q = apply_rope(q, pos[:, None], rope_theta)
     k_new = apply_rope(k_new, pos[:, None], rope_theta)
     arena_k = scatter_token_pages(arena_k, block_table, pos, k_new, active)
@@ -375,7 +390,7 @@ def apply_attention_decode_paged(
 def apply_attention_prefill_paged(
     params, x, arena_k, arena_v, row_table, pos0, n_valid, *, num_heads,
     num_kv_heads, head_dim, rope_theta, policy=None, q_chunk=64,
-    kv_chunk=128,
+    kv_chunk=128, norm_eps=1e-6,
 ):
     """One K-token prefill chunk of ONE sequence against the paged arena.
 
@@ -390,7 +405,7 @@ def apply_attention_prefill_paged(
     """
     b, k_tok, _ = x.shape
     q, k_new, v_new = _project_qkv(params, x, x, num_heads, num_kv_heads,
-                                   head_dim, policy)
+                                   head_dim, policy, norm_eps)
     apos = pos0 + jnp.arange(k_tok)
     q = apply_rope(q, apos[None, :], rope_theta)
     k_new = apply_rope(k_new, apos[None, :], rope_theta)

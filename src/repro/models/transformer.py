@@ -75,7 +75,7 @@ def ring_decode_attention(params_block, x, cache, pos, *, cfg: ArchConfig,
     b = x.shape[0]
     hq, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     q, k_new, v_new = attn._project_qkv(params_block, x, x, hq, hkv, dh,
-                                        policy)
+                                        policy, cfg.norm_eps)
     q = apply_rope(q, pos[:, None], cfg.rope_theta)
     k_new = apply_rope(k_new, pos[:, None], cfg.rope_theta)
     w = cache["k"].shape[1]
@@ -111,7 +111,8 @@ def init_tblock(key, cfg: ArchConfig, *, cross=False, dtype=jnp.float32):
         "ln1": init_rmsnorm(d, dtype),
         "attn": attn.init_attention(
             ks[0], d, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim,
-            sparse=sp if "attn_qkv" in cfg.sparse_scope else None, dtype=dtype),
+            sparse=sp if "attn_qkv" in cfg.sparse_scope else None, dtype=dtype,
+            qk_norm=cfg.qk_norm),
         "ln2": init_rmsnorm(d, dtype),
     }
     if cross:
@@ -133,26 +134,27 @@ def init_tblock(key, cfg: ArchConfig, *, cross=False, dtype=jnp.float32):
 def apply_tblock_seq(blk, x, cfg: ArchConfig, *, window, positions=None,
                      enc_out=None, causal=True, static_window=None,
                      policy):
-    h = apply_rmsnorm(blk["ln1"], x)
+    h = apply_rmsnorm(blk["ln1"], x, cfg.norm_eps)
     h = attn.apply_attention(
         blk["attn"], h,
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
         positions=positions, causal=causal, window=window,
-        static_window=static_window, policy=policy)
+        static_window=static_window, policy=policy, norm_eps=cfg.norm_eps)
     x = x + h
     if "xattn" in blk and enc_out is not None:
-        h = apply_rmsnorm(blk["ln_x"], x)
+        h = apply_rmsnorm(blk["ln_x"], x, cfg.norm_eps)
         h = attn.apply_attention(
             blk["xattn"], h,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
             causal=False, window=-1, kv_x=enc_out, policy=policy)
         x = x + h
-    h = apply_rmsnorm(blk["ln2"], x)
+    h = apply_rmsnorm(blk["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if "moe" in blk:
-        h, aux = moe_mod.apply_moe(blk["moe"], h, cfg.moe, policy=policy)
+        h, aux = moe_mod.apply_moe(blk["moe"], h, cfg.moe, policy=policy,
+                                   capacity=moe_mod.BY_FACTOR)
     else:
         h = apply_mlp(blk["mlp"], h, policy=policy)
     return x + h, aux
@@ -251,7 +253,7 @@ class DecoderLM:
                     static_window=cfg.local_window, positions=positions,
                     policy=policy)
                 aux = aux + a
-            return apply_rmsnorm(params["final_norm"], x), aux
+            return apply_rmsnorm(params["final_norm"], x, cfg.norm_eps), aux
 
         static_window = cfg.window if cfg.attention == "swa" else None
         windows = layer_windows(cfg)
@@ -267,7 +269,7 @@ class DecoderLM:
         body = _remat(body, cfg)
         (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                    (params["layers"], windows))
-        return apply_rmsnorm(params["final_norm"], x), aux
+        return apply_rmsnorm(params["final_norm"], x, cfg.norm_eps), aux
 
     def _embed_inputs(self, params, batch, dtype):
         cfg = self.cfg
@@ -378,44 +380,58 @@ class DecoderLM:
             }
         return {"caches": caches, "pos": jnp.zeros((batch,), jnp.int32)}
 
+    @property
+    def routes_experts(self) -> bool:
+        """Whether the paged programs (``decode_step`` on a paged state,
+        ``prefill_chunk``) return routing counts as a third output."""
+        return self.cfg.moe is not None
+
     def _decode_ffn(self, blk, x, policy):
+        """The FFN half of a serving layer: ``(x + ffn(x), counts)``, where
+        ``counts`` is the MoE layer's int32 ``[assignments routed, experts
+        with a row]`` (dropless dispatch) and None for a dense MLP."""
         cfg = self.cfg
-        h = apply_rmsnorm(blk["ln2"], x)
+        h = apply_rmsnorm(blk["ln2"], x, cfg.norm_eps)
         if "moe" in blk:
-            h, _ = moe_mod.apply_moe(blk["moe"], h, cfg.moe, policy=policy)
-        else:
-            h = apply_mlp(blk["mlp"], h, policy=policy)
-        return x + h
+            h, _, counts = moe_mod.apply_moe(blk["moe"], h, cfg.moe,
+                                             policy=policy, stats=True)
+            return x + h, counts
+        return x + apply_mlp(blk["mlp"], h, policy=policy), None
 
     def _decode_full_layer(self, blk, x, cache, pos, window, policy):
         cfg = self.cfg
-        h = apply_rmsnorm(blk["ln1"], x)
+        h = apply_rmsnorm(blk["ln1"], x, cfg.norm_eps)
         h, nc = attn.apply_attention_decode(
             blk["attn"], h, cache, pos,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            window=window, policy=policy)
-        return self._decode_ffn(blk, x + h, policy), nc
+            window=window, policy=policy, norm_eps=cfg.norm_eps)
+        return self._decode_ffn(blk, x + h, policy)[0], nc
 
     def _decode_ring_layer(self, blk, x, cache, pos, window, policy):
-        h = apply_rmsnorm(blk["ln1"], x)
+        h = apply_rmsnorm(blk["ln1"], x, self.cfg.norm_eps)
         h, nc = ring_decode_attention(blk["attn"], h, cache, pos,
                                       cfg=self.cfg, window=window, policy=policy)
-        return self._decode_ffn(blk, x + h, policy), nc
+        return self._decode_ffn(blk, x + h, policy)[0], nc
 
     def _decode_paged_layer(self, blk, x, arena_k, arena_v, bt, active, pos,
                             policy):
         cfg = self.cfg
-        h = apply_rmsnorm(blk["ln1"], x)
+        h = apply_rmsnorm(blk["ln1"], x, cfg.norm_eps)
         h, arenas = attn.apply_attention_decode_paged(
             blk["attn"], h, arena_k, arena_v, bt, active, pos,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            window=FULL_WINDOW, policy=policy)
-        return self._decode_ffn(blk, x + h, policy), arenas
+            window=FULL_WINDOW, policy=policy, norm_eps=cfg.norm_eps)
+        x, counts = self._decode_ffn(blk, x + h, policy)
+        return x, arenas, counts
 
     def decode_step(self, params, state, tokens, *, policy=None,
                           mode=None, backend=None):
+        """One token for every slot.  On a paged state, a model that
+        :attr:`routes_experts` returns a third output: the MoE layers'
+        routing counts summed over layers, int32 ``[assignments routed,
+        experts that received a row]``."""
         policy = resolve_policy(policy, mode, backend)
         cfg = self.cfg
         dtype = dtype_of(cfg.compute_dtype)
@@ -441,20 +457,23 @@ class DecoderLM:
 
             def body(x, layer):
                 blk, ak, av = layer
-                x, arenas = self._decode_paged_layer(
+                x, arenas, counts = self._decode_paged_layer(
                     blk, x, ak, av, bt, active, pos, policy)
-                return x, arenas
+                return x, (arenas, counts)
 
-            x, (ks, vs) = jax.lax.scan(
+            x, ((ks, vs), counts) = jax.lax.scan(
                 body, x, (params["layers"], caches["k"], caches["v"]))
             new_caches = {**caches, "k": ks, "v": vs}
-            x = apply_rmsnorm(params["final_norm"], x)
+            x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
             logits = apply_unembedding(params["unembed"], x,
                                        self.cfg.vocab_size)
             # only lanes decoding this tick advance; prefilling/empty slots
             # keep their position (their pages were null-redirected too)
-            return logits, {"caches": new_caches,
-                            "pos": pos + active.astype(jnp.int32)}
+            new_state = {"caches": new_caches,
+                         "pos": pos + active.astype(jnp.int32)}
+            if self.routes_experts:
+                return logits, new_state, counts.sum(0)
+            return logits, new_state
 
         elif kind == "swa":
             def body(x, layer):
@@ -511,7 +530,7 @@ class DecoderLM:
                           "tail": tail_caches, "global_k": gks,
                           "global_v": gvs}
 
-        x = apply_rmsnorm(params["final_norm"], x)
+        x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = apply_unembedding(params["unembed"], x, self.cfg.vocab_size)
         return logits, {"caches": new_caches, "pos": pos + 1}
 
@@ -601,7 +620,7 @@ class DecoderLM:
             "k": new_stage["k"].reshape(l, *caches["k"].shape[1:]),
             "v": new_stage["v"].reshape(l, *caches["v"].shape[1:]),
         }
-        x = apply_rmsnorm(params["final_norm"], x)
+        x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = apply_unembedding(params["unembed"], x, self.cfg.vocab_size)
         return logits, {"caches": new_caches, "pos": pos + 1}
 
@@ -614,7 +633,9 @@ class DecoderLM:
         compiled program serves every chunk of every request —
         O(prompt_len / K) dispatches instead of O(prompt_len).  Returns the
         logits at the last *valid* position (shape ``(1, 1, V)``) so the
-        final chunk yields the first sampled token for free.
+        final chunk yields the first sampled token for free.  A model that
+        :attr:`routes_experts` returns a third output as :meth:`decode_step`
+        gives it (the padding past ``n_valid`` is routed too, and counted).
         """
         policy = resolve_policy(policy, mode, backend)
         cfg = self.cfg
@@ -632,21 +653,25 @@ class DecoderLM:
 
         def body(x, layer):
             blk, ak, av = layer
-            h = apply_rmsnorm(blk["ln1"], x)
+            h = apply_rmsnorm(blk["ln1"], x, cfg.norm_eps)
             h, arenas = attn.apply_attention_prefill_paged(
                 blk["attn"], h, ak, av, row, pos0, n_valid,
                 num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                policy=policy)
-            return self._decode_ffn(blk, x + h, policy), arenas
+                policy=policy, norm_eps=cfg.norm_eps)
+            x, counts = self._decode_ffn(blk, x + h, policy)
+            return x, (arenas, counts)
 
-        x, (ks, vs) = jax.lax.scan(
+        x, ((ks, vs), counts) = jax.lax.scan(
             body, x, (params["layers"], caches["k"], caches["v"]))
-        x = apply_rmsnorm(params["final_norm"], x)
+        x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
         last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         logits = apply_unembedding(params["unembed"], last, cfg.vocab_size)
-        return logits, {"caches": {**caches, "k": ks, "v": vs},
-                        "pos": state["pos"].at[slot].add(n_valid)}
+        new_state = {"caches": {**caches, "k": ks, "v": vs},
+                     "pos": state["pos"].at[slot].add(n_valid)}
+        if self.routes_experts:
+            return logits, new_state, counts.sum(0)
+        return logits, new_state
 
 
 # ---------------------------------------------------------------------------

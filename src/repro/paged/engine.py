@@ -43,6 +43,13 @@ device trace's clock, and their host seconds add up in
 two compiled programs are named ``decode_step`` and ``prefill_chunk``
 (``jit_decode_step``/``jit_prefill_chunk`` in a trace), and
 ``serve_compiles_total{program}`` counts each new executable they build.
+
+A mixture-of-experts model (``model.routes_experts``) has both programs
+return their routing counts beside their outputs; the engine reads them
+with the device reads it already makes (a decode step's logits, a
+prompt's last chunk) into ``serve_moe_rows_total{program}`` (assignments
+routed) and ``serve_moe_experts_touched_total{program}`` (experts that
+received a row, summed over layers).  A dense model counts nothing.
 """
 
 from __future__ import annotations
@@ -121,6 +128,8 @@ class PagedServeEngine(EngineBase):
             cfg.num_slots, cfg.max_len, dtype=jnp.float32,
             paged=self.layout))
 
+        self._moe = bool(getattr(model, "routes_experts", False))
+
         def decode_step(p, s, t):
             return model.decode_step(p, s, t, policy=policy)
 
@@ -183,6 +192,17 @@ class PagedServeEngine(EngineBase):
             "serve_compiles_total",
             help="executables a dispatch of the program traced and compiled",
             program=p) for p in self._jits}
+        # routing counts still on the device: (program, int32 [rows, experts])
+        self._moe_pending = []
+        if self._moe:
+            self._m_moe_rows = {p: m.counter(
+                "serve_moe_rows_total",
+                help="expert assignments routed (tokens x top-k, summed "
+                     "over layers)", program=p) for p in self._jits}
+            self._m_moe_touched = {p: m.counter(
+                "serve_moe_experts_touched_total",
+                help="experts that received at least one row, summed over "
+                     "layers", program=p) for p in self._jits}
         self._m_slots = m.gauge(
             "serve_slots_active", help="occupied decode slots")
         self._m_queue_depth = m.gauge(
@@ -303,6 +323,26 @@ class PagedServeEngine(EngineBase):
         grown = fn._cache_size() - before
         if grown > 0:
             self._m_compiles[program].inc(grown)
+
+    def _decode_call(self, *args):
+        """One decode-step dispatch: (logits, state), its routing counts
+        queued for the next device read."""
+        out = self._decode(*args)
+        if not self._moe:
+            return out
+        self._moe_pending.append(("decode", out[2]))
+        return out[0], out[1]
+
+    def _read_moe(self):
+        """Add the queued routing counts to their counters (the programs
+        that made them have finished: this follows a device read)."""
+        if not self._moe_pending:
+            return
+        got = jax.device_get([c for _, c in self._moe_pending])
+        for (program, _), (rows, touched) in zip(self._moe_pending, got):
+            self._m_moe_rows[program].inc(int(rows))
+            self._m_moe_touched[program].inc(int(touched))
+        self._moe_pending = []
 
     def _page_gauges(self):
         self._m_pages_free.set(self.kv.pages_free)
@@ -472,6 +512,9 @@ class PagedServeEngine(EngineBase):
                     with self._dispatch("prefill", uid=req.uid):
                         logits, self.state, fed = self._prefill_step(
                             self.params, self.state, self._work[i], was, i)
+                        if self._moe:
+                            self._moe_pending.append(
+                                ("prefill", self.prefill.last_counts))
                     self.trace.event("prefill_chunk", uid=req.uid, slot=i,
                                      fed_from=was, fed_to=fed)
                 self._fed[i] = fed
@@ -483,6 +526,7 @@ class PagedServeEngine(EngineBase):
                 if fed == len(self._work[i]):
                     with self._phase("prefill.wait", uid=req.uid):
                         row = np.asarray(logits[0, 0], np.float32)
+                        self._read_moe()
                     with self._phase("sample"):
                         self._finish_prefill(i, req, row, time.monotonic())
             with self._phase("pages"):
@@ -528,11 +572,12 @@ class PagedServeEngine(EngineBase):
         # batched dispatch: attributed to the first decode-ready lane
         with obs.use_context(self._request_context(self.active[first])), \
                 self._dispatch("decode"):
-            logits, self.state = self._decode(
+            logits, self.state = self._decode_call(
                 self.params, self.state,
                 jnp.asarray(np.array(self._next_tok)))
         with self._phase("decode.wait"):
             logits = np.asarray(logits[:, 0], np.float32)   # device sync
+            self._read_moe()
         step_dt = time.perf_counter() - t0
         self._m_disp_decode.inc()
         now = time.monotonic()
@@ -583,11 +628,12 @@ class PagedServeEngine(EngineBase):
         window_ctx = self._request_context(self.active[lanes[0]])
         for j in range(g_eff):
             with obs.use_context(window_ctx), self._dispatch("decode"):
-                d_logits, d_state = self._decode(
+                d_logits, d_state = self._decode_call(
                     self._draft_params, d_state,
                     jnp.asarray(window[:, j:j + 1]))
             with self._phase("decode.wait"):
                 d_logits = np.asarray(d_logits[:, 0], np.float32)
+                self._read_moe()
             self._m_disp_draft.inc()
             with self._phase("sample"):
                 for i in lanes:

@@ -25,7 +25,9 @@ class ChunkedPrefill:
     ``model`` needs a ``prefill_chunk(params, state, tokens, slot, n_valid,
     policy=...)`` method (DecoderLM / EncDecLM).  ``step`` runs one chunk —
     the unit the scheduler interleaves with decode ticks; ``ingest`` loops a
-    whole prompt (benchmarks, tests).
+    whole prompt (benchmarks, tests).  Where the program also returns MoE
+    routing counts (a model that ``routes_experts``), each chunk's are kept
+    (on the device, not read) in ``last_counts``.
     """
 
     def __init__(self, model, *, chunk: int = 32, policy=None):
@@ -41,6 +43,7 @@ class ChunkedPrefill:
             return model.prefill_chunk(p, s, t, slot, n, policy=policy)
 
         self._fn = jax.jit(prefill_chunk)
+        self.last_counts = None       # the last chunk's routing counts
         self.dispatches = 0           # compiled-program invocations issued
 
     def num_chunks(self, prompt_len: int) -> int:
@@ -54,8 +57,10 @@ class ChunkedPrefill:
         part = np.asarray(prompt[fed:fed + self.chunk], np.int32)
         buf = np.zeros((self.chunk,), np.int32)
         buf[:len(part)] = part
-        logits, state = self._fn(params, state, jnp.asarray(buf),
-                                 jnp.int32(slot), jnp.int32(len(part)))
+        logits, state, *counts = self._fn(params, state, jnp.asarray(buf),
+                                          jnp.int32(slot),
+                                          jnp.int32(len(part)))
+        self.last_counts = counts[0] if counts else None
         self.dispatches += 1
         return logits, state, fed + len(part)
 

@@ -38,8 +38,8 @@ def _rules():
     return [
         # embeddings / unembedding: vocab-sharded
         (r"(embed|unembed)/table", lambda nd: P("model", None)),
-        # MoE expert tensors (E, in, out): EP over model
-        (r"moe/w_(gate|up|down)", lambda nd: P("model", None, None)),
+        # MoE expert stacks (E, out, in): EP over model
+        (r"moe/(gate|up|down)/w", lambda nd: P("model", None, None)),
         (r"moe/router/w", lambda nd: P(None, None)),
         # attention projections: column-parallel q/k/v, row-parallel o
         (r"(attn|xattn)/w[qkv]/w", "col"),
@@ -272,7 +272,7 @@ def param_specs(params, **_kw) -> dict:
 
 def _base_ndim(path: str, nd: int) -> int:
     """Arity of the unstacked tensor for this path."""
-    if re.search(r"moe/w_(gate|up|down)", path):
+    if re.search(r"moe/(gate|up|down)/w", path):
         return 3
     if re.search(r"blk/r$", path):
         return 3

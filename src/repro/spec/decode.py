@@ -106,8 +106,9 @@ def make_multistep(model, policy):
 
     def multistep(params, state, tokens):
         def body(st, tok_col):
-            logits, st = model.decode_step(params, st, tok_col[:, None],
-                                           policy=policy)
+            # a mixture-of-experts model's routing counts are not kept here
+            logits, st, *_ = model.decode_step(params, st, tok_col[:, None],
+                                               policy=policy)
             return st, logits[:, 0]
 
         state_out, logits = jax.lax.scan(body, state,

@@ -102,6 +102,14 @@ def resolve_xwT_block(x_shape, pw, dtype) -> TunedConfig:
     return default_cache().resolve(p)
 
 
+def resolve_experts(x_shape, pw, dtype) -> TunedConfig:
+    """Static (backend, params) choice for ``backend="auto"`` dispatch of a
+    packed expert stack (values ``(E, G, Ne, O)``).  Never measures."""
+    p = Problem.for_experts(x_shape, pw.dense_shape, pw.cfg, dtype,
+                            pw.values.shape[0])
+    return default_cache().resolve(p)
+
+
 def autotune_packed_tree(params, batch: int, dtype=None, *,
                          persist: bool = True, **tune_kw) -> dict:
     """Pre-tune every distinct packed-weight matmul shape in a param pytree.

@@ -74,6 +74,8 @@ def problem_key(p: Problem) -> str:
         # block geometry is fixed at pack time, so two packings of the same
         # weight are distinct dispatch problems (pre-block keys unchanged).
         key += f"|b{p.block_r}x{p.a_max}"
+    if p.experts:
+        key += f"|e{p.experts}"
     if p.shards > 1:
         # Shard-local problem of a renumbered row-parallel weight (k and
         # a_max above are already the per-shard values): keep TP slices

@@ -31,6 +31,10 @@ Ops and uniform signatures
 ``xwT_block_q8``: call(x, values, indices, active_groups, scales, cfg,
                 w_shape, **params) -> (B, O) — the quantized two-level
                 layout, scales (RB, A_max, block_r).
+``experts``   : call(x, group_sizes, values, indices, cfg, w_shape,
+                **params) -> (R, O) — rows of x sorted by expert
+                (``group_sizes[e]`` rows for expert e) through a stack of
+                packed experts, values/indices (E, G, Ne, O).
 
 A :class:`Problem` is the static description of one matmul instance — shapes,
 dtype, sparsity pattern, platform — and is everything a variant needs to
@@ -46,7 +50,7 @@ import jax
 
 from repro.core.sparsity import SparsityConfig
 
-OPS = ("xwT", "spmm", "xwT_block", "xwT_q8", "xwT_block_q8")
+OPS = ("xwT", "spmm", "xwT_block", "xwT_q8", "xwT_block_q8", "experts")
 
 
 def current_platform() -> str:
@@ -82,6 +86,7 @@ class Problem:
     block_r: int = 0
     a_max: int = 0
     shards: int = 1
+    experts: int = 0                # stack depth E (``experts`` only)
 
     def __post_init__(self):
         if self.op not in OPS:
@@ -121,6 +126,18 @@ class Problem:
                    k=int(b_shape[0]), dtype=jax.numpy.dtype(dtype).name,
                    sparsity=(cfg.n, cfg.m, cfg.k),
                    platform=platform or current_platform())
+
+    @classmethod
+    def for_experts(cls, x_shape, w_shape, cfg: SparsityConfig, dtype,
+                    experts: int,
+                    platform: Optional[str] = None) -> "Problem":
+        """``x_shape`` is the sorted rows' (R, K), ``w_shape`` one expert's
+        dense (O, K)."""
+        return cls(op="experts", rows=int(x_shape[0]), out=int(w_shape[0]),
+                   k=int(x_shape[1]), dtype=jax.numpy.dtype(dtype).name,
+                   sparsity=(cfg.n, cfg.m, cfg.k),
+                   platform=platform or current_platform(),
+                   experts=int(experts))
 
     @classmethod
     def for_xwT_block(cls, x_shape, pw, dtype,
@@ -271,6 +288,46 @@ def _register_builtin_variants():
         param_space=xwT_tiles, default_params=xwT_defaults,
         supported=lambda p: p.dense_flops <= _INTERPRET_FLOP_LIMIT,
         description="Pallas kernel in interpret mode (CPU checks)"))
+
+    from repro.kernels.demm_experts import (DEFAULT_BLOCK_B,
+                                            demm_experts_pallas)
+
+    def experts_ref_call(x, group_sizes, values, indices, cfg, w_shape,
+                         **_):
+        return kref.experts_ref(x, group_sizes, values, indices, cfg,
+                                w_shape)
+
+    def experts_pallas_call(x, group_sizes, values, indices, cfg, w_shape,
+                            *, interpret, block_b=DEFAULT_BLOCK_B, **_):
+        count_scatter_slices("experts",
+                             group_chunk(values.shape[1], cfg.m), cfg.m)
+        return demm_experts_pallas(x, group_sizes, values, indices, cfg,
+                                   block_b=block_b, interpret=interpret)
+
+    def experts_tiles(p: Problem):
+        return {"block_b": _legal_tiles(p.rows, 8, 512, SUBLANES)}
+
+    def experts_defaults(p: Problem):
+        return {"block_b": fit_tile(p.rows, DEFAULT_BLOCK_B, SUBLANES)}
+
+    register_variant(KernelVariant(
+        op="experts", name="reference", call=experts_ref_call,
+        param_space=lambda p: {}, default_params=lambda p: {},
+        supported=lambda p: True,
+        description="every expert's dense product, each row kept from its "
+                    "own expert (XLA path)"))
+    register_variant(KernelVariant(
+        op="experts", name="pallas",
+        call=lambda *a, **kw: experts_pallas_call(*a, interpret=False, **kw),
+        param_space=experts_tiles, default_params=experts_defaults,
+        supported=lambda p: p.platform == "tpu",
+        description="grouped Pallas TPU kernel over packed experts"))
+    register_variant(KernelVariant(
+        op="experts", name="pallas_interpret",
+        call=lambda *a, **kw: experts_pallas_call(*a, interpret=True, **kw),
+        param_space=experts_tiles, default_params=experts_defaults,
+        supported=lambda p: p.dense_flops <= _INTERPRET_FLOP_LIMIT,
+        description="grouped kernel in interpret mode (CPU checks)"))
 
     def spmm_ref_call(values, indices, b, cfg, a_shape, **_):
         return kref.spmm_ref(values, indices, b, cfg, a_shape)

@@ -13,6 +13,9 @@ The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU library, so with several pytest
 workers only the worker running this file loads it.  Where it cannot be
 described the tests skip.
+
+The grouped expert kernel (``demm_experts``) and OLMoE-1B-7B's two serving
+programs are compiled at that model's published widths too.
 """
 
 import os
@@ -24,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.sparsity import SparsityConfig
 from repro.kernels.demm_block_spmm import demm_block_xwT_pallas
+from repro.kernels.demm_experts import demm_experts_pallas
 from repro.kernels.demm_q8 import (demm_block_xwT_q8_pallas,
                                    demm_xwT_q8_pallas)
 from repro.kernels.demm_spmm import demm_xwT_pallas
@@ -186,3 +190,81 @@ def test_tp4_packed_decode_step_compiles_for_v5e(topo, layout):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text          # the row-parallel psum islands
+
+
+# OLMoE-1B-7B's expert stacks: 64 experts, (O, K) of gate/up and of down,
+# over the rows of a 4-slot decode step and of a 256-token chunk (top-8)
+EXPERTS = 64
+EXPERT_SHAPES = [(1024, 2048), (2048, 1024)]
+EXPERT_ROWS = (32, 2048)
+
+
+@pytest.mark.parametrize("shape", EXPERT_SHAPES)
+@pytest.mark.parametrize("rows", EXPERT_ROWS)
+def test_experts_kernel_compiles_for_v5e(one_chip, shape, rows):
+    o, k = shape
+    cfg = SparsityConfig(8, 128)
+    core = (EXPERTS, k // 128, 8, o)
+
+    def s(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    compiled = jax.jit(lambda *a: demm_experts_pallas(*a, cfg)).lower(
+        s((rows, k), jnp.bfloat16), s((EXPERTS,), jnp.int32),
+        s(core, jnp.float32), s(core, jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the stack goes to the kernel as stored: no relayout of it
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        EXPERTS * o * k // 16
+    # the call's first operand has rows (the group offsets), not a scalar
+    # grid bound: the benchmark's generic kernel costing reads them
+    call = next(ln for ln in text.splitlines()
+                if "demm_experts" in ln and "tpu_custom_call" in ln)
+    first = call.split("operand_layout_constraints={", 1)[1].split(", ")[0]
+    assert first.startswith(f"s32[{EXPERTS + 1}]"), first
+
+
+def test_olmoe_serving_programs_compile_for_v5e(one_chip):
+    """The packed decode step and prefill chunk of OLMoE-1B-7B at full
+    width (16 layers, 64 experts a layer) on the olmoe_1b_7b.docqa cell's
+    engine (4 slots, 1024 pages of 16, chunk 256) fit one chip: the params,
+    the K/V arena held twice across a step, and the temporaries."""
+    from repro.configs.base import get_arch
+    from repro.core.sparse_linear import ExecPolicy
+    from repro.launch.pack_tree import pack_tree
+    from repro.models.families import build_model
+    from repro.paged import PagedLayout
+
+    model = build_model(get_arch("olmoe_1b_7b"))
+    params = jax.eval_shape(
+        lambda k: pack_tree(model.init(k, layer_fn=pack_tree)),
+        jax.random.PRNGKey(0))
+    layout = PagedLayout.for_serve(4096, page_size=16, num_pages=1024,
+                                   num_slots=4)
+    state = jax.eval_shape(lambda: model.init_decode_state(
+        4, 4096, dtype=jnp.float32, paged=layout))
+
+    def put(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def scalar(shape=()):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    policy = ExecPolicy(mode="packed", backend="pallas")
+    programs = {
+        "decode": (lambda p, s, t: model.decode_step(
+            p, s, t, policy=policy),
+            (put(params), put(state), scalar((4, 1)))),
+        "prefill": (lambda p, s, t, slot, n: model.prefill_chunk(
+            p, s, t, slot, n, policy=policy),
+            (put(params), put(state), scalar((256,)), scalar(), scalar())),
+    }
+    for name, (fn, args) in programs.items():
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text(), name
+        mem = compiled.memory_analysis()
+        peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+        assert peak < 16e9, (name, mem)

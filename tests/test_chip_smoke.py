@@ -61,6 +61,17 @@ def test_kernel_phase_interpret(smoke, capsys):
         "xwT", "xwT_q8", "xwT_block", "xwT_block_q8"]
 
 
+def test_experts_phase_interpret(smoke, capsys):
+    """The grouped expert kernel's check at reduced widths: both routings
+    of each row count, empty experts among them."""
+    smoke.experts_phase(shapes={"gate": (128, 256), "down": (256, 128)},
+                        rows=(8, 64), experts=8, top_k=2, pattern="2:32",
+                        backend="pallas_interpret")
+    line = capsys.readouterr().out.strip()
+    assert line.split()[:3] == ["kernel", "experts", "backend=pallas_interpret"]
+    assert "cases=8" in line and "empty_experts=0" not in line
+
+
 def test_serve_phase_reduced_interpret(smoke, capsys):
     cfg, model, params = smoke.build_model(full=False)
     tokens = smoke.serve_phase(cfg, model, params,

@@ -232,3 +232,81 @@ def test_spmm_op_backends_agree():
                    "pallas_interpret")
     np.testing.assert_allclose(np.asarray(r1), np.asarray(r2), rtol=1e-4,
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Grouped packed matmul over a stack of experts (demm_experts)
+# ---------------------------------------------------------------------------
+
+EXPERT_CASES = [
+    # (n, m, experts, out, groups, group sizes, block_b, dtype)
+    (2, 32, 4, 256, 4, (3, 0, 9, 4), 8, jnp.float32),      # tiles shared
+    (2, 32, 4, 64, 2, (0, 0, 16, 0), 8, jnp.float32),      # all on one
+    (1, 16, 3, 128, 8, (5, 6, 5), 128, jnp.float32),       # one row tile
+    (2, 32, 8, 256, 8, (1, 0, 2, 3, 0, 0, 9, 1), 8, jnp.float32),
+    (8, 128, 4, 128, 2, (0, 7, 0, 9), 8, jnp.bfloat16),
+    (5, 80, 2, 128, 16, (8, 8), 8, jnp.bfloat16),           # 8 groups a step
+]
+
+
+def _expert_stack(rng, experts, out, k, cfg, dtype):
+    """Per-expert dense pruned matrices and their packed stack."""
+    dense = [random_sparse_dense(rng, out, k, cfg).astype(np.float32)
+             for _ in range(experts)]
+    packed = [pack(jnp.asarray(w, dtype), cfg) for w in dense]
+    return (dense, jnp.stack([p.values for p in packed]),
+            jnp.stack([p.indices for p in packed]))
+
+
+@pytest.mark.parametrize("n,m,experts,out,groups,sizes,bb,dtype",
+                         EXPERT_CASES)
+def test_experts_kernel_vs_per_expert_loop(n, m, experts, out, groups,
+                                           sizes, bb, dtype):
+    """Each row through its own expert's matrix, as a loop over experts
+    computes it; empty experts and every row on one expert included."""
+    from repro.kernels.demm_experts import demm_experts_pallas
+
+    rng = np.random.default_rng(n * 100 + experts)
+    cfg = SparsityConfig(n, m)
+    k = groups * m
+    dense, values, indices = _expert_stack(rng, experts, out, k, cfg, dtype)
+    rows = sum(sizes)
+    x = jnp.asarray(rng.standard_normal((rows, k)), dtype)
+    got = demm_experts_pallas(x, jnp.asarray(sizes, jnp.int32), values,
+                              indices, cfg, block_b=bb, interpret=True)
+    xs = np.asarray(x, np.float32)
+    want = np.zeros((rows, out), np.float32)
+    start = 0
+    for e, size in enumerate(sizes):
+        w = np.asarray(jnp.asarray(dense[e], dtype), np.float32)
+        want[start:start + size] = xs[start:start + size] @ w.T
+        start += size
+    np.testing.assert_allclose(np.asarray(got), want, **_tol(dtype))
+    oracle = kref.experts_ref(x, jnp.asarray(sizes, jnp.int32), values,
+                              indices, cfg, (out, k))
+    np.testing.assert_allclose(np.asarray(oracle), want, **_tol(dtype))
+
+
+def test_experts_op_dispatch():
+    """``ops.demm_experts_packed`` routes an expert-stack PackedWeight
+    through the registry: backends agree; stacked or quantized weights
+    are refused."""
+    from repro.core.sparsity import PackedWeight
+    from repro.kernels.ops import demm_experts_packed
+
+    rng = np.random.default_rng(8)
+    cfg = SparsityConfig(2, 32)
+    _, values, indices = _expert_stack(rng, 4, 64, 128, cfg, jnp.float32)
+    pw = PackedWeight(values, indices, cfg=cfg, dense_shape=(64, 128))
+    x = jnp.asarray(rng.standard_normal((12, 128)), jnp.float32)
+    sizes = jnp.asarray([5, 0, 3, 4], jnp.int32)
+    outs = {be: np.asarray(demm_experts_packed(x, sizes, pw, be))
+            for be in ("reference", "pallas_interpret", "auto")}
+    np.testing.assert_allclose(outs["pallas_interpret"], outs["reference"],
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(outs["auto"], outs["reference"], rtol=1e-4,
+                               atol=1e-5)
+    stacked = PackedWeight(values[None], indices[None], cfg=cfg,
+                           dense_shape=(64, 128))
+    with pytest.raises(ValueError, match="expert stack"):
+        demm_experts_packed(x, sizes, stacked, "reference")

@@ -76,7 +76,7 @@ cfg = MoEConfig(num_experts=8, experts_per_token=2, d_ff_expert=32)
 params = moe_mod.init_moe(jax.random.PRNGKey(0), 64, cfg, sparse=None)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64))
 
-y_ref, aux_ref = moe_mod._apply_moe_local(params, x, cfg, capacity=64)
+y_ref, aux_ref = moe_mod.apply_moe(params, x, cfg, capacity=64)  # no mesh: local
 
 mesh = make_test_mesh((2, 4), ("data", "model"))
 ctx = shctx.make_context(mesh, num_kv_heads=16)
@@ -87,6 +87,33 @@ with shctx.use_mesh(ctx):
 np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
                            rtol=2e-2, atol=2e-2)
 print("MoE EP matches local dispatch")
+""")
+
+
+def test_moe_expert_parallel_counts_the_whole_batch():
+    """The EP path's routing counts are the whole batch's, not one data
+    shard's: the same as the local dispatch gives."""
+    run_with_devices("""
+import jax, numpy as np
+from repro.launch.mesh import make_test_mesh
+from repro.configs.base import MoEConfig
+from repro.models import moe as moe_mod
+from repro.sharding import context as shctx
+
+cfg = MoEConfig(num_experts=8, experts_per_token=2, d_ff_expert=32)
+params = moe_mod.init_moe(jax.random.PRNGKey(0), 64, cfg, sparse=None)
+x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64))
+_, _, st_ref = moe_mod.apply_moe(params, x, cfg, capacity=64, stats=True)
+
+mesh = make_test_mesh((2, 4), ("data", "model"))
+ctx = shctx.make_context(mesh, num_kv_heads=16)
+with shctx.use_mesh(ctx):
+    _, _, st_ep = jax.jit(lambda p, x: moe_mod.apply_moe(
+        p, x, cfg, capacity=64, stats=True))(params, x)
+assert np.asarray(st_ep).tolist() == np.asarray(st_ref).tolist(), (st_ep,
+                                                                   st_ref)
+assert int(st_ep[0]) == 4 * 8 * 2
+print("MoE EP counts the whole batch")
 """)
 
 
